@@ -50,7 +50,6 @@ from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import codegen
@@ -197,6 +196,13 @@ class ShardedEngine:
         self._step_cache: Dict[tuple, Callable] = {}
         self._serve_cache: Dict[tuple, Callable] = {}
         self._custom_cache: Dict[str, Callable] = {}
+
+    @property
+    def blocks(self) -> Dict[str, Dict[str, jax.Array]]:
+        """Per-group post-sharded connectivity: {"g", "post", "valid"
+        [, "delay"]} each [D, n_pre, K_local], or {"dense"}
+        [D, n_pre, shard]; shard d lives on mesh device d."""
+        return self._blocks
 
     # ------------------------------------------------------------------
     # state layout
@@ -780,8 +786,8 @@ class ShardedEngine:
         return (self._state_specs, self._block_specs, self._pn_specs)
 
     def _shard_map(self, fn, in_specs, out_specs):
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
     def _make_run(self, n_steps: int, keys: Tuple[str, ...],
                   record_raster: bool, stim_keys: Tuple[str, ...] = ()):

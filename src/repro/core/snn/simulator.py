@@ -42,7 +42,9 @@ conductance is detected without host round-trips.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import threading
 from typing import Dict, Mapping, Optional, Tuple
 
 import jax
@@ -146,11 +148,37 @@ class Simulator:
         self._group_names = {g.name for g in net.synapses}
         self._groups = {g.name: g for g in net.synapses}
         self._run_jit_cache: Dict[tuple, object] = {}
+        self._bind_lock = threading.RLock()
         # --- probes + custom updates (ModelSpec passes these resolved) ---
         self.probes = tuple(probes)
         self.custom_updates = {cu.name: cu for cu in custom_updates}
         self._scheduled = [cu for cu in custom_updates
                            if cu.every is not None]
+
+    # ------------------------------------------------------------------
+    # connectivity as a jit argument
+    # ------------------------------------------------------------------
+    def connectivity(self) -> Dict[str, tuple]:
+        """Every synapse group's device arrays, {name: (ell, dense)}: the
+        argument jitted programs take (with `bound`) instead of capturing
+        the connectivity, which jit would embed in the compiled program as
+        constants — gigabytes at 10^5 neurons x 10^3 synapses."""
+        return {g.name: (g.ell, g.dense) for g in self.net.synapses}
+
+    @contextlib.contextmanager
+    def bound(self, conn: Dict[str, tuple]):
+        """Trace with `conn` (the traced twin of `connectivity()`) standing
+        in for each group's arrays.  Serialized: tracing swaps the groups'
+        attributes, and the gateway traces from its pump thread."""
+        with self._bind_lock:
+            saved = self.connectivity()
+            try:
+                for g in self.net.synapses:
+                    g.ell, g.dense = conn[g.name]
+                yield
+            finally:
+                for g in self.net.synapses:
+                    g.ell, g.dense = saved[g.name]
 
     def _validate_gscales(
             self, gscales: Optional[Mapping[str, jax.Array]]) -> None:
@@ -475,11 +503,14 @@ class Simulator:
         if cache_key not in self._run_jit_cache:
 
             @jax.jit
-            def _run(state, gscales):
-                return self.run(state, n_steps, gscales,
-                                record_raster=record_raster)
+            def _run(conn, state, gscales):
+                with self.bound(conn):
+                    return self.run(state, n_steps, gscales,
+                                    record_raster=record_raster)
 
-            self._run_jit_cache[cache_key] = _run
+            self._run_jit_cache[cache_key] = (
+                lambda state, gscales: _run(self.connectivity(), state,
+                                            gscales))
         return self._run_jit_cache[cache_key]
 
     # ------------------------------------------------------------------

@@ -942,15 +942,17 @@ class CompiledModel:
             sim = self.simulator
 
             @jax.jit
-            def _run(st, vals, stim_v):
-                return sim.run(st, n_steps, dict(zip(keys, vals)),
-                               record_raster=record_raster, stim=stim_v)
+            def _run(conn, st, vals, stim_v):
+                with sim.bound(conn):
+                    return sim.run(st, n_steps, dict(zip(keys, vals)),
+                                   record_raster=record_raster, stim=stim_v)
 
             self._run_cache[cache_key] = _run
         vals = tuple(gscales[k] for k in keys)
         with trace.span("run", model=self.spec.name, n_steps=n_steps,
                         sharded=False, compile=compiled):
-            return self._run_cache[cache_key](state, vals, stim)
+            return self._run_cache[cache_key](
+                self.simulator.connectivity(), state, vals, stim)
 
     def sweep_gscale(self, group: Union[str, Sequence[str]],
                      values, n_steps: int,
@@ -973,16 +975,17 @@ class CompiledModel:
             sim = self.simulator
 
             @jax.jit
-            def _sweep(st, vals):
+            def _sweep(conn, st, vals):
                 def one(gval):
                     res = sim.run(st, n_steps, {n: gval for n in names})
                     return (res.rates_hz, res.finite, res.spike_counts,
                             res.recordings)
-                return jax.vmap(one)(vals)
+                with sim.bound(conn):
+                    return jax.vmap(one)(vals)
 
             self._sweep_cache[cache_key] = _sweep
-        rates, finite, counts, rec = self._sweep_cache[cache_key](state,
-                                                                  values)
+        rates, finite, counts, rec = self._sweep_cache[cache_key](
+            self.simulator.connectivity(), state, values)
         return SweepResult(values=values, rates_hz=rates, finite=finite,
                            spike_counts=counts, recordings=rec)
 
@@ -1031,10 +1034,11 @@ class CompiledModel:
             sim = self.simulator
 
             @jax.jit
-            def _serve(st, stim_v, left, vals):
-                return sim.serve_chunk(st, stim_v, left, n_steps,
-                                       dict(zip(keys, vals)),
-                                       record_raster=record_raster)
+            def _serve(conn, st, stim_v, left, vals):
+                with sim.bound(conn):
+                    return sim.serve_chunk(st, stim_v, left, n_steps,
+                                           dict(zip(keys, vals)),
+                                           record_raster=record_raster)
 
             self._run_cache[cache_key] = _serve
         vals = tuple(gscales[k] for k in keys)
@@ -1042,7 +1046,8 @@ class CompiledModel:
         with trace.span("serve_chunk", model=self.spec.name,
                         n_steps=n_steps, streams=n_streams, sharded=False,
                         compile=compiled):
-            return self._run_cache[cache_key](state, stim, steps_left, vals)
+            return self._run_cache[cache_key](
+                self.simulator.connectivity(), state, stim, steps_left, vals)
 
     def serve(self, max_streams: int = 4, chunk: int = 50, **kwargs):
         """A streaming SNNServer over this model: `max_streams` device-
@@ -1080,9 +1085,15 @@ class CompiledModel:
         cache_key = ("custom_update", name)
         if cache_key not in self._run_cache:
             sim = self.simulator
-            self._run_cache[cache_key] = jax.jit(
-                lambda st: sim.custom_update(st, name))
-        return self._run_cache[cache_key](state)
+
+            @jax.jit
+            def _update(conn, st):
+                with sim.bound(conn):
+                    return sim.custom_update(st, name)
+
+            self._run_cache[cache_key] = _update
+        return self._run_cache[cache_key](self.simulator.connectivity(),
+                                          state)
 
     def memory_report(self, n_steps: Optional[int] = None,
                       max_streams: int = 1) -> List[dict]:

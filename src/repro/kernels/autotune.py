@@ -27,10 +27,13 @@ import dataclasses
 import math
 from typing import Dict, Sequence, Tuple
 
+import jax
+
 from repro.obs import trace as _trace
 
 __all__ = [
-    "TPULimits", "V5E", "occupancy", "choose_block_elementwise",
+    "TPULimits", "V5E", "TPU_LIMITS", "UnknownTPUError", "device_limits",
+    "occupancy", "choose_block_elementwise",
     "choose_block_matmul", "choose_block_spmv", "spmv_block_bytes",
     "choose_propagation", "occupancy_report",
 ]
@@ -38,7 +41,7 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class TPULimits:
-    """Per-core resource limits (v5e defaults)."""
+    """Per-core resource limits of one TPU generation (defaults: v5e)."""
 
     vmem_bytes: int = 16 * 1024 * 1024     # usable VMEM per core
     lane: int = 128                        # vector lane count
@@ -49,6 +52,33 @@ class TPULimits:
 
 
 V5E = TPULimits()
+
+#: limits by ``jax.Device.device_kind``; a TPU kind missing here is an error
+TPU_LIMITS: Dict[str, TPULimits] = {"TPU v5 lite": V5E}
+
+
+class UnknownTPUError(LookupError):
+    """The attached TPU's device_kind has no TPULimits entry."""
+
+
+def device_limits(device=None) -> TPULimits:
+    """Tile limits for `device` (default: the first JAX device).
+
+    On a TPU they are looked up by ``device_kind``: a kind without an entry
+    in TPU_LIMITS raises UnknownTPUError rather than tiling for another
+    chip.  Off the TPU (CPU runs, Pallas interpret mode, compiles for a
+    described topology) the tiles are those of a v5e, the chip they model.
+    """
+    dev = jax.devices()[0] if device is None else device
+    if dev.platform != "tpu":
+        return V5E
+    try:
+        return TPU_LIMITS[dev.device_kind]
+    except KeyError:
+        raise UnknownTPUError(
+            f"no TPULimits for TPU device_kind {dev.device_kind!r}; known "
+            f"kinds: {sorted(TPU_LIMITS)} (add its VMEM and tile limits to "
+            "repro.kernels.autotune.TPU_LIMITS)") from None
 
 
 def _align_penalty(shape: Tuple[int, ...], dtype_bytes: int,
@@ -152,30 +182,31 @@ def choose_block_matmul(
 def spmv_block_bytes(bp: int, bn: int, k: int, b: int,
                      dtype_bytes: int = 4, n_slots: int = 1) -> int:
     """VMEM working set of one ELL-spmv grid step (repro.kernels.ell_spmv):
-    spike tile [B, BP], g + idx tiles [BP, K], output tile [B, BN], plus the
-    in-kernel one-hot materialization [BP*K, BN] and the K-expanded spike
-    tile [B, BP*K] — the one-hot temporary is the VMEM driver.
+    spike tile [B, BP], transposed g + idx row tiles [K, BP], output tile
+    [B, BN], plus the weight tile [BN, BP] the kernel rebuilds in a scratch
+    buffer and the row-index iota and select temporary of the same shape —
+    the weight tile and the row tiles are the VMEM drivers.
 
-    n_slots > 1 describes the fused-delay variant: a third [BP, K] row tile
-    (the delay slots) and a (delay, post)-combined one-hot/output whose post
-    extent is n_slots * BN."""
-    m = bp * k
+    n_slots > 1 describes the fused-delay variant: a third [K, BP] row tile
+    (the delay slots) and a (delay, post)-combined weight tile/output whose
+    post extent is n_slots * BN."""
     row_tiles = 2 if n_slots == 1 else 3
-    return (b * bp + row_tiles * bp * k + b * bn * n_slots
-            + m * bn * n_slots + b * m) * dtype_bytes
+    return (b * bp + row_tiles * k * bp + b * bn * n_slots
+            + 3 * bn * n_slots * bp) * dtype_bytes
 
 
 def choose_block_spmv(
     n_pre: int, k: int, n_post: int, b: int, dtype_bytes: int = 4,
-    lim: TPULimits = V5E, tag: str = "", n_slots: int = 1,
+    lim: TPULimits | None = None, tag: str = "", n_slots: int = 1,
 ) -> Dict[str, int]:
-    """Pick (bp, bn) tiles for the ELL one-hot-matmul spmv via the
-    occupancy model (paper §3: smallest block that still hides latency;
-    ties prefer larger tiles / fewer grid steps).
+    """Pick (bp, bn) tiles for the ELL spmv kernel via the occupancy model
+    (paper §3: smallest block that still hides latency; ties prefer larger
+    tiles / fewer grid steps).  Both are multiples of 128: bp is the lane
+    dimension of the spike and row tiles, bn that of the output tile.
 
-    The kernel loads full-K row tiles, so for very wide rows (K beyond a
-    few thousand slots) *no* (bp, bn) fits VMEM: the result then carries
-    ``feasible: False`` and the minimum (8, 128) tiling — callers
+    The kernel loads full-K row tiles, so for very wide rows (K beyond
+    several thousand slots) *no* (bp, bn) fits VMEM: the result then carries
+    ``feasible: False`` and the minimum (128, 128) tiling — callers
     (repro.kernels.ell_spmv) split K into feasible chunks and sum.
 
     Every decision is recorded as a ``choose_block_spmv`` trace instant
@@ -183,23 +214,24 @@ def choose_block_spmv(
     and VMEM footprint; ``tag`` attributes it (e.g. a synapse group name).
 
     n_slots > 1 sizes the fused-delay variant (repro.kernels.ell_spmv.
-    ell_spmv_delay_pallas): output and one-hot tiles grow by the number of
-    dendritic-delay ring slots.
+    ell_spmv_delay_pallas): output and weight tiles grow by the number of
+    dendritic-delay ring slots.  ``lim`` defaults to device_limits().
     """
+    lim = device_limits() if lim is None else lim
     bn_candidates = [bn for bn in (128, 256, 512, 1024)
                      if bn <= max(128, math.ceil(n_post / lim.lane)
                                   * lim.lane)]
     best = None
     for bn in bn_candidates:
-        bp = lim.sublane_f32
-        while bp <= max(lim.sublane_f32, 1 << 14):
-            if bp > n_pre and bp != lim.sublane_f32:
+        bp = lim.lane
+        while bp <= max(lim.lane, 1 << 14):
+            if bp > n_pre and bp != lim.lane:
                 break
             grid = math.ceil(n_post / bn) * math.ceil(n_pre / bp)
             blk = spmv_block_bytes(bp, bn, k, b, dtype_bytes, n_slots)
             occ = occupancy(blk, grid,
-                            [(bp, k), (b, bp), (b, bn * n_slots),
-                             (bp * k, bn * n_slots)],
+                            [(k, bp), (b, bp), (b, bn * n_slots),
+                             (bn * n_slots, bp)],
                             dtype_bytes, lim)
             key = (occ, bp * bn)           # ties -> bigger tile
             if best is None or key > best[0]:
@@ -208,11 +240,11 @@ def choose_block_spmv(
                               "block_bytes": blk, "feasible": occ > 0.0})
             bp *= 2
     if best is None or best[0][0] <= 0.0:
-        blk = spmv_block_bytes(lim.sublane_f32, lim.lane, k, b, dtype_bytes,
+        blk = spmv_block_bytes(lim.lane, lim.lane, k, b, dtype_bytes,
                                n_slots)
-        cfg = {"bp": lim.sublane_f32, "bn": lim.lane, "occupancy": 0.0,
+        cfg = {"bp": lim.lane, "bn": lim.lane, "occupancy": 0.0,
                "grid": (math.ceil(n_post / lim.lane)
-                        * math.ceil(n_pre / lim.sublane_f32)),
+                        * math.ceil(n_pre / lim.lane)),
                "block_bytes": blk,
                "feasible": blk * lim.double_buffer <= lim.vmem_bytes}
     else:
@@ -225,7 +257,7 @@ def choose_block_spmv(
 def choose_propagation(
     n_pre: int, k: int, n_post: int, b: int = 1, activity: float = 0.1,
     capacity: int | None = None, n_slots: int = 1, dtype_bytes: int = 4,
-    lim: TPULimits = V5E, tag: str = "",
+    lim: TPULimits | None = None, tag: str = "",
 ) -> Dict[str, object]:
     """Occupancy/activity-model crossover: dense full-matrix spmv vs
     event-driven row gathering for one synapse group (paper's sparse
@@ -247,6 +279,7 @@ def choose_propagation(
     the modelled slot counts; records a ``choose_propagation`` trace
     instant.
     """
+    lim = device_limits() if lim is None else lim
     if capacity is None:
         q = lim.sublane_f32
         cap = math.ceil(n_pre * activity * 2.5 / q) * q

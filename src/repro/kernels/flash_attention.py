@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.autotune import V5E, TPULimits
+from repro.kernels.autotune import TPULimits, device_limits
 
 __all__ = ["flash_attention_pallas", "default_blocks"]
 
@@ -92,9 +92,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def default_blocks(tq: int, tk: int, d: int,
-                   lim: TPULimits = V5E) -> tuple[int, int]:
+                   lim: TPULimits | None = None) -> tuple[int, int]:
     """qb/kb from the occupancy model: working set = q + k + v + logits +
-    acc tiles (x2 double-buffered) under the VMEM budget, dims 128-aligned."""
+    acc tiles (x2 double-buffered) under the VMEM budget, dims 128-aligned.
+    ``lim`` defaults to the attached device's (autotune.device_limits)."""
+    lim = device_limits() if lim is None else lim
     qb = min(512, max(128, tq))
     kb = min(1024, max(128, tk))
     while (qb * d + 2 * kb * d + qb * kb + qb * d) * 4 * lim.double_buffer \

@@ -1,16 +1,20 @@
 """Jit'd dispatching wrappers around the Pallas kernels.
 
-Dispatch policy (see DESIGN.md §3):
+Dispatch policy (``REPRO_USE_PALLAS``, parsed by repro.flags.pallas_mode):
   REPRO_USE_PALLAS=1          -> compiled Pallas kernels (real TPU)
-  REPRO_USE_PALLAS=interpret  -> Pallas interpret mode (CPU validation)
-  unset/0                     -> pure-jnp reference (CPU dry-runs, rooflines)
+  REPRO_USE_PALLAS=interpret  -> Pallas interpret mode (CPU validation);
+                                 refused with InterpretOnTPUError on a TPU
+  unset/0                     -> pure-jnp reference (the default everywhere,
+                                 the TPU included)
 
-The public functions keep one signature regardless of backend so the rest of
-the system never branches.
+The backend is read when a wrapper is traced, so a jitted program keeps the
+backend it was traced with.  The public functions keep one signature
+regardless of backend so the rest of the system never branches.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -20,20 +24,32 @@ from repro import flags as _flags
 from repro.kernels import ref as _ref
 
 __all__ = [
-    "backend", "use_pallas", "ell_spmv", "ell_spmv_batched",
-    "ell_spmv_delay", "ell_spmv_delay_batched", "ell_spmv_event",
-    "ell_spmv_event_delay", "izhikevich_step", "hh_step",
+    "backend", "use_pallas", "InterpretOnTPUError", "ell_spmv",
+    "ell_spmv_batched", "ell_spmv_delay", "ell_spmv_delay_batched",
+    "ell_spmv_event", "ell_spmv_event_delay", "izhikevich_step", "hh_step",
     "flash_attention", "ssd_scan",
 ]
 
 
+class InterpretOnTPUError(RuntimeError):
+    """REPRO_USE_PALLAS=interpret while JAX's default backend is a TPU."""
+
+
 def backend() -> str:
     """Kernel backend: 'ref' | 'pallas' | 'interpret'.  The env parse lives
-    in repro.flags.pallas_mode (one site; misspellings raise)."""
+    in repro.flags.pallas_mode (one site; misspellings raise).  Interpret
+    mode on a TPU raises InterpretOnTPUError: the chip would run the kernels
+    through the interpreter, slowly and without saying so."""
     mode = _flags.pallas_mode()
     if mode is _flags.PallasMode.ON:
         return "pallas"
     if mode is _flags.PallasMode.INTERPRET:
+        if jax.default_backend() == "tpu":
+            raise InterpretOnTPUError(
+                "REPRO_USE_PALLAS=interpret on a TPU would interpret the "
+                "Pallas kernels instead of compiling them; set "
+                "REPRO_USE_PALLAS=1 for the compiled kernels or unset it for "
+                "the reference path")
         return "interpret"
     return "ref"
 
@@ -56,6 +72,31 @@ def ell_spmv_batched(ell, spikes: jax.Array) -> jax.Array:
                            interpret=(be == "interpret"))
 
 
+def _per_sample(fn):
+    """Decorate `fn(ell, spikes, *static)` of one sample so that under vmap
+    it runs once per sample in a loop.
+
+    Serving vmaps the step over streams (sweep_gscale over values).
+    Batched, the reference scatter puts the batch in the minor dimension of
+    its updates (32x padding on a TPU), and a batched ``lax.cond``
+    predicate turns event propagation into a select that also runs the
+    dense fallback for every sample.  In the loop each sample runs the
+    offline program, so a served stream stays bit-exact against its
+    offline run on every backend.  One custom_vmap per static arguments:
+    a jaxpr names its batching rule by identity, so equal programs keep
+    equal jaxprs."""
+    @functools.lru_cache(maxsize=None)
+    def looped(*static):
+        return jax.custom_batching.sequential_vmap(
+            lambda ell, spikes: fn(ell, spikes, *static))
+
+    @functools.wraps(fn)
+    def wrapper(ell, spikes: jax.Array, *static) -> jax.Array:
+        return looped(*static)(ell, spikes)
+    return wrapper
+
+
+@_per_sample
 def ell_spmv(ell, spikes: jax.Array) -> jax.Array:
     """spikes [n_pre] -> currents [n_post]."""
     return ell_spmv_batched(ell, spikes[None, :])[0]
@@ -75,6 +116,7 @@ def ell_spmv_delay_batched(ell, spikes: jax.Array, n_slots: int) -> jax.Array:
                                  interpret=(be == "interpret"))
 
 
+@_per_sample
 def ell_spmv_delay(ell, spikes: jax.Array, n_slots: int) -> jax.Array:
     """spikes [n_pre] -> ring contributions [n_slots, n_post]."""
     return ell_spmv_delay_batched(ell, spikes[None, :], n_slots)[0]
@@ -105,6 +147,7 @@ def _compact_rows(ell, spikes: jax.Array, capacity: int):
     return ell_c, spk_c, count
 
 
+@_per_sample
 def ell_spmv_event(ell, spikes: jax.Array, capacity: int) -> jax.Array:
     """Event-driven spmv: gather only the spiking rows (fixed capacity);
     more than `capacity` simultaneous spikes falls back to the dense pass.
@@ -117,6 +160,7 @@ def ell_spmv_event(ell, spikes: jax.Array, capacity: int) -> jax.Array:
         lambda: ell_spmv(ell, spk))
 
 
+@_per_sample
 def ell_spmv_event_delay(ell, spikes: jax.Array, n_slots: int,
                          capacity: int) -> jax.Array:
     """Event-driven fused delay-scatter: spikes [n_pre] ->

@@ -67,7 +67,7 @@ from repro.obs import profile as obs_profile
 from repro.obs.telemetry import LatencyWindow, PromText
 
 __all__ = ["Gateway", "GatewayRequest", "GatewayOverloaded",
-           "GatewayWorker", "LatencyWindow"]
+           "GatewayFailed", "GatewayWorker", "LatencyWindow"]
 
 
 class GatewayOverloaded(RuntimeError):
@@ -87,15 +87,21 @@ class GatewayOverloaded(RuntimeError):
         self.retry_after_s = retry_after_s
 
 
+class GatewayFailed(RuntimeError):
+    """Raised by submit once the serving loop has failed
+    (Gateway.fail_pending): no request would ever be served."""
+
+
 @dataclasses.dataclass
 class GatewayRequest(StreamRequest):
     """A StreamRequest with gateway semantics: priority class, deadline,
     and a lifecycle the client can wait on.
 
-    status: queued -> active -> done | evicted.  An evicted request keeps
-    every chunk streamed before its deadline (partial results); ``done``
-    stays False.  ``wait`` blocks until the request leaves the gateway
-    either way.
+    status: queued -> active -> done | evicted | failed.  An evicted
+    request keeps every chunk streamed before its deadline (partial
+    results); ``done`` stays False.  ``failed`` means the serving loop
+    itself raised (Gateway.fail_pending).  ``wait`` blocks until the
+    request leaves the gateway any of these ways.
     """
 
     model: str = ""
@@ -103,6 +109,7 @@ class GatewayRequest(StreamRequest):
     deadline_ms: Optional[float] = None     # relative to submit
     deadline_at: Optional[float] = None     # absolute clock() time
     status: str = "queued"
+    error: Optional[str] = None             # why, when status == "failed"
     _done_evt: threading.Event = dataclasses.field(
         default_factory=threading.Event, repr=False)
 
@@ -345,6 +352,7 @@ class Gateway:
         self._rid = itertools.count()
         self._lock = threading.RLock()
         self.started_at = clock()
+        self.failure: Optional[str] = None     # set by fail_pending
 
     # -- registration -------------------------------------------------------
     def register(self, name: str, model, stim_pops=None, buckets=None,
@@ -376,8 +384,11 @@ class Gateway:
         """Submit one stimulus stream; returns the live GatewayRequest
         (wait() on it, or poll .status).  Raises GatewayOverloaded when the
         model's admission queue is full and KeyError/ValueError for an
-        unknown model or malformed stimulus."""
+        unknown model or malformed stimulus, and GatewayFailed once the
+        serving loop has failed."""
         with self._lock:
+            if self.failure is not None:
+                raise GatewayFailed(self.failure)
             if model not in self.workers:
                 raise KeyError(
                     f"unknown model {model!r}; registered: "
@@ -402,6 +413,21 @@ class Gateway:
     def has_work(self) -> bool:
         with self._lock:
             return any(w.sched.has_work() for w in self.workers.values())
+
+    def fail_pending(self, reason: str) -> int:
+        """Mark every request that has not left the gateway ``failed``,
+        wake its waiters and refuse later submits with GatewayFailed; for
+        a serving loop that cannot continue (the HTTP pump calls it when a
+        tick raises).  Returns the number of requests failed."""
+        with self._lock:
+            self.failure = reason
+            pending = [r for w in self.workers.values()
+                       for r in w.requests.values()
+                       if r.status in ("queued", "active")]
+            for r in pending:
+                r.error = reason
+                r._finish("failed")
+            return len(pending)
 
     def run_until_drained(self) -> None:
         while self.tick():
@@ -553,4 +579,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -19,8 +19,10 @@ Routes:
          chunks streamed before eviction are returned, not discarded)
       -> 429 + Retry-After header when the admission queue is full
       -> 400 unknown model / malformed stimulus
+      -> 500 the pump thread failed (a tick raised, e.g. a compile error):
+         every pending and later request gets the recorded error
   GET /metrics     Prometheus-style text (Gateway.render_metrics)
-  GET /healthz     200 "ok"
+  GET /healthz     200 "ok"; 500 with the error once the pump has failed
   GET /v1/trace    Chrome trace_event JSON of the process's build/serve
                    spans so far (open in chrome://tracing / Perfetto) —
                    a debug endpoint, not a stable API
@@ -33,12 +35,16 @@ from __future__ import annotations
 
 import asyncio
 import json
+import sys
 import threading
+import time
+import traceback
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.launch.gateway import Gateway, GatewayOverloaded
+from repro.launch.gateway import (Gateway, GatewayFailed,
+                                  GatewayOverloaded)
 from repro.obs import trace as obs_trace
 
 __all__ = ["GatewayHTTP", "serve_http"]
@@ -73,6 +79,11 @@ class GatewayHTTP:
     the gateway lock for a whole compiled chunk, and a thread keeps that
     entirely off the event loop.  It idles at ``idle_sleep_s`` when no
     model has work, so an empty gateway costs ~nothing.
+
+    If a tick raises, the pump prints the traceback, records the error as
+    the gateway's ``failure`` (Gateway.fail_pending fails every pending
+    request) and stops: /healthz and every /v1/simulate then answer 500
+    with that error instead of hanging.
     """
 
     def __init__(self, gateway: Gateway, host: str = "127.0.0.1",
@@ -106,10 +117,14 @@ class GatewayHTTP:
             await self._server.wait_closed()
 
     def _pump_loop(self) -> None:
-        import time
-        while not self._stop.is_set():
-            if not self.gateway.tick():
-                time.sleep(self.idle_sleep_s)
+        try:
+            while not self._stop.is_set():
+                if not self.gateway.tick():
+                    time.sleep(self.idle_sleep_s)
+        except Exception as e:    # thread boundary: report, record, fail
+            traceback.print_exc(file=sys.stderr)
+            self.gateway.fail_pending(
+                f"gateway pump failed: {type(e).__name__}: {e}")
 
     # -- request handling -----------------------------------------------------
     async def _handle(self, reader: asyncio.StreamReader,
@@ -139,6 +154,8 @@ class GatewayHTTP:
             headers[k.strip().lower()] = v.strip()
 
         if method == "GET" and path == "/healthz":
+            if self.gateway.failure is not None:
+                return _json_response(500, {"error": self.gateway.failure})
             return _response(200, b"ok\n", "text/plain")
         if method == "GET" and path == "/metrics":
             return _response(200, self.gateway.render_metrics().encode(),
@@ -180,6 +197,8 @@ class GatewayHTTP:
                 429, {"error": str(e), "retry_after_s": e.retry_after_s},
                 extra_headers=(("Retry-After",
                                 f"{max(1, int(e.retry_after_s + 0.5))}"),))
+        except GatewayFailed as e:
+            return _json_response(500, {"error": str(e)})
         except (KeyError, ValueError) as e:
             return _json_response(400, {"error": str(e)})
         # wait for completion/eviction off the event loop; the deadline
@@ -190,6 +209,8 @@ class GatewayHTTP:
         finished = await loop.run_in_executor(None, req.wait, timeout)
         if not finished:
             return _json_response(500, {"error": "request stalled"})
+        if req.status == "failed":
+            return _json_response(500, {"error": req.error})
         timing = self.gateway.workers[model].sched.timings.get(req.rid)
         out = {
             "rid": req.rid,

@@ -45,13 +45,8 @@ def init_distributed(coordinator_address: Optional[str] = None,
     explicitly for bare multi-process launches.  Idempotent: a second
     call (or an already-initialized runtime) is a no-op."""
     if not _DISTRIBUTED["initialized"]:
-        try:
-            # the CPU backend needs an explicit cross-process collectives
-            # implementation; must be set before the backend initializes,
-            # which is exactly when this function runs.  No-op on GPU/TPU.
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):
-            pass  # older jax: CPU multi-process simply unsupported
+        # CPU cross-process collectives use gloo, the installed JAX's
+        # default for jax_cpu_collectives_implementation
         try:
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,
@@ -66,24 +61,11 @@ def init_distributed(coordinator_address: Optional[str] = None,
     return jax.process_index(), jax.process_count()
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """`axis_types` compatibility shim: jax.sharding.AxisType only exists in
-    newer jax releases (and older jax.make_mesh rejects the kwarg).  Auto is
-    the default there anyway, so omitting it preserves semantics."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_mesh(shape, axes):
-    kwargs = _axis_type_kwargs(len(axes))
-    try:
-        return jax.make_mesh(shape, axes, **kwargs)
-    except TypeError:
-        # jax new enough to have AxisType but make_mesh not accepting the
-        # kwarg (or vice-versa mid-release): fall back to defaults.
-        return jax.make_mesh(shape, axes)
+    """A mesh over the first prod(shape) devices with Auto (compiler-
+    propagated) sharding on every axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

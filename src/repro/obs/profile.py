@@ -4,10 +4,10 @@
 phases are recorded both as trace spans (so they land in the exported
 Chrome trace) and as a simple (name, seconds) table the CLIs print.
 
-:func:`jax_profiler_trace` wraps ``jax.profiler.trace`` when available
-(XLA-level timelines, TensorBoard-loadable) and degrades to a no-op with a
-warning otherwise, so ``--jax-profile DIR`` never breaks a build without
-profiler support.
+:func:`jax_profiler_trace` wraps ``jax.profiler.trace`` (XLA-level
+timelines, TensorBoard-loadable).  A profiler that cannot start raises
+:class:`ProfilerUnavailable`: a run that asked for a device trace must not
+finish without one.
 """
 from __future__ import annotations
 
@@ -18,8 +18,12 @@ from typing import List, Optional, Tuple
 
 from repro.obs import trace as _trace
 
-__all__ = ["PhaseTimer", "jax_profiler_trace", "write_trace",
-           "export_trace_cli"]
+__all__ = ["PhaseTimer", "ProfilerUnavailable", "jax_profiler_trace",
+           "write_trace", "export_trace_cli"]
+
+
+class ProfilerUnavailable(RuntimeError):
+    """jax.profiler could not start a trace."""
 
 
 class PhaseTimer:
@@ -50,17 +54,19 @@ class PhaseTimer:
 
 @contextmanager
 def jax_profiler_trace(logdir: str):
-    """jax.profiler.trace(logdir) when supported, else a warning no-op."""
+    """jax.profiler.trace(logdir); raises ProfilerUnavailable when the
+    profiler cannot start."""
+    import jax.profiler as _prof
     try:
-        import jax.profiler as _prof
-        ctx = _prof.trace(logdir)
-    except Exception as e:  # profiler unavailable in this build
-        print(f"[obs] jax profiler unavailable ({e}); continuing without",
-              file=sys.stderr)
+        _prof.start_trace(logdir)
+    except Exception as e:  # whatever the profiler backend raises
+        raise ProfilerUnavailable(
+            f"jax profiler could not start a trace in {logdir!r}: "
+            f"{type(e).__name__}: {e}") from e
+    try:
         yield
-        return
-    with ctx:
-        yield
+    finally:
+        _prof.stop_trace()
 
 
 def write_trace(path: str,

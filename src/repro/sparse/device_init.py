@@ -42,7 +42,6 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import snn_axis
@@ -59,6 +58,7 @@ __all__ = [
 _JTriple = Tuple[jax.Array, jax.Array, jax.Array]  # post_ind, g, valid
 
 _MAX_REDRAW_ROUNDS = 64  # residual-duplicate probability < 2**-64 per slot
+_LANE = 128              # TPU vector lanes: the slot axis is padded to these
 
 
 # ---------------------------------------------------------------------------
@@ -337,42 +337,46 @@ def _partition_rows(
 
     Slot (i, k) goes to the shard owning post neuron post_ind[i, k],
     compacted left and re-indexed to shard-local post ids; the within-row
-    slot order is preserved (stable argsort), so per-post-neuron scatter
+    slot order is preserved (stable sort), so per-post-neuron scatter
     accumulation order — and hence bit-exact currents — matches the input
     slot order.  All ops are per-row independent: partitioning a chunk of
     rows bit-matches the corresponding rows of a full-matrix partition.
+
+    The slot axis is first padded with invalid slots to whole 128-lane
+    tiles, and the rows are sorted by shard with their payload in one
+    multi-operand sort, then gathered per shard: compiled for a TPU,
+    gathering or scattering along an unaligned K (1,000) is a relayout
+    whose compile time grows with the slot count.
     """
-    n_rows, k = g.shape
+    pad = -g.shape[1] % _LANE
+    if pad:
+        g, post_ind, valid, delay = (
+            None if a is None else jnp.pad(a, ((0, 0), (0, pad)))
+            for a in (g, post_ind, valid, delay))
     shard = jnp.where(valid, post_ind // shard_size, n_shards)
-    order = jnp.argsort(shard, axis=1)            # stable in jax
-    shard_s = jnp.take_along_axis(shard, order, axis=1)
-    post_s = jnp.take_along_axis(post_ind, order, axis=1)
-    g_s = jnp.take_along_axis(jnp.where(valid, g, 0.0), order, axis=1)
-    delay_s = (None if delay is None else jnp.take_along_axis(
-        jnp.where(valid, delay, 0), order, axis=1))
+    payload = (post_ind.astype(jnp.int32),
+               jnp.where(valid, g, 0.0).astype(jnp.float32))
+    if delay is not None:
+        payload += (jnp.where(valid, delay, 0).astype(jnp.int32),)
+    shard_s, *sorted_ = jax.lax.sort((shard,) + payload, dimension=1,
+                                     num_keys=1, is_stable=True)
     bounds = jnp.arange(n_shards + 1, dtype=shard_s.dtype)
     edges = jax.vmap(
         lambda row: jnp.searchsorted(row, bounds, side="left"))(shard_s)
-    counts = jnp.diff(edges, axis=1)              # [n_rows, n_shards]
-    start = jnp.concatenate(
-        [jnp.zeros((n_rows, 1), counts.dtype),
-         jnp.cumsum(counts, axis=1)[:, :-1]], axis=1)   # exclusive prefix
-    d_idx = shard_s                                # [n_rows, k]
-    slot = jnp.arange(k, dtype=jnp.int32)[None, :] - jnp.take_along_axis(
-        start, jnp.clip(d_idx, 0, n_shards - 1), axis=1)
-    row = jnp.broadcast_to(jnp.arange(n_rows)[:, None], (n_rows, k))
-    shape = (n_shards, n_rows, k_local)
-    # invalid slots carry d_idx == n_shards -> dropped by the OOB mode
-    g_out = jnp.zeros(shape, jnp.float32).at[d_idx, row, slot].set(
-        g_s, mode="drop")
-    post_out = jnp.zeros(shape, jnp.int32).at[d_idx, row, slot].set(
-        (post_s - d_idx * shard_size).astype(jnp.int32), mode="drop")
-    valid_out = jnp.zeros(shape, bool).at[d_idx, row, slot].set(
-        shard_s < n_shards, mode="drop")
-    delay_out = (None if delay_s is None
-                 else jnp.zeros(shape, jnp.int32).at[d_idx, row, slot].set(
-                     delay_s.astype(jnp.int32), mode="drop"))
-    return g_out, post_out, valid_out, delay_out
+    j = jnp.arange(k_local, dtype=edges.dtype)[None, :]
+    blocks = []                    # per shard: (g, post, valid[, delay])
+    for d in range(n_shards):
+        idx = edges[:, d:d + 1] + j                 # [n_rows, k_local]
+        ok = idx < edges[:, d + 1:d + 2]
+        idx = jnp.where(ok, idx, 0)
+        post_d, g_d, *delay_d = (
+            jnp.where(ok, jnp.take_along_axis(a, idx, axis=1), 0)
+            for a in sorted_)
+        blocks.append((g_d, jnp.where(ok, post_d - d * shard_size, 0), ok,
+                       *delay_d))
+    g_out, post_out, valid_out, *delay_out = (jnp.stack(a) for a in
+                                              zip(*blocks))
+    return g_out, post_out, valid_out, (delay_out[0] if delay_out else None)
 
 
 def partition_ell_by_post(
@@ -512,9 +516,9 @@ def device_init_local(
             osum = jnp.zeros((), jnp.int32)
         return kmax.reshape(1), osum.reshape(1)
 
-    counted = jax.jit(shard_map(
+    counted = jax.jit(jax.shard_map(
         count_fn, mesh=mesh, in_specs=(P(),),
-        out_specs=(P(), P()), check_rep=False))(key)
+        out_specs=(P(), P()), check_vma=False))(key)
     k_local = max(1, int(jax.device_get(counted[0])[0]))
     if is_fp:
         overflow = int(jax.device_get(counted[1])[0])
@@ -539,10 +543,10 @@ def device_init_local(
         return tuple(out)
 
     n_out = 4 if has_delay else 3
-    outs = jax.jit(shard_map(
+    outs = jax.jit(jax.shard_map(
         fill_fn, mesh=mesh, in_specs=(P(),),
         out_specs=tuple(P(axis, None, None) for _ in range(n_out)),
-        check_rep=False))(key)
+        check_vma=False))(key)
     g_out = outs[0][:, :n_pre]
     post_out = outs[1][:, :n_pre]
     valid_out = outs[2][:, :n_pre]
@@ -560,7 +564,7 @@ def construction_peak_model(n_pre: int, k: int, n_devices: int, k_local: int,
     block).  Used by `ModelSpec.plan` and the scaling bench — the fused
     number is the O(nnz / device) claim, stated in bytes."""
     slot_b = F.ell_slot_bytes(has_delay)
-    # argsort order (i4) + sorted shard ids (i4) + sorted copies of each slot
+    # shard ids (i4) + sorted shard ids (i4) + sorted copies of each slot
     # array: the transient working set of `_partition_rows` per source slot
     tmp_b = 8 + slot_b
     rows_local = -(-n_pre // n_devices)

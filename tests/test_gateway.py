@@ -391,6 +391,60 @@ def test_http_front_door_end_to_end(host_model):
     asyncio.run(scenario())
 
 
+def test_http_pump_failure_answers_500(host_model):
+    """A tick that raises (say, a compile error on the chip) must not kill
+    the pump thread silently: the error is recorded, /healthz and every
+    pending or later request answer 500 with it, and nothing hangs."""
+    n = host_model.network.populations["exc"].n
+
+    class BrokenGateway(Gateway):
+        def tick(self):
+            if self.has_work():
+                raise RuntimeError("tick exploded")
+            return False
+
+    async def scenario():
+        gw = BrokenGateway(chunk=6, buckets=(2,), warm=False)
+        gw.register("izh", host_model, stim_pops=("exc",))
+        srv = GatewayHTTP(gw, "127.0.0.1", 0, idle_sleep_s=0.001)
+        host, port = await srv.start()
+
+        async def http(method, path, body=None):
+            reader, writer = await asyncio.open_connection(host, port)
+            payload = b"" if body is None else json.dumps(body).encode()
+            writer.write((f"{method} {path} HTTP/1.1\r\nHost: t\r\n"
+                          f"Content-Length: {len(payload)}\r\n\r\n")
+                         .encode() + payload)
+            await writer.drain()
+            raw = await reader.read()
+            writer.close()
+            head, _, body_ = raw.partition(b"\r\n\r\n")
+            status = int(head.split()[1])
+            return status, json.loads(body_) if status == 500 else body_
+
+        body = {"model": "izh", "n_steps": 6, "seed": 1,
+                "stim": {"exc": np.zeros((6, n)).tolist()}}
+        try:
+            status, _ = await http("GET", "/healthz")
+            assert status == 200
+            # the pending request fails with the pump's error ...
+            status, out = await asyncio.wait_for(
+                http("POST", "/v1/simulate", body), timeout=30)
+            assert status == 500 and "tick exploded" in out["error"]
+            assert gw.failure is not None
+            # ... and so do health checks and later submits
+            status, out = await http("GET", "/healthz")
+            assert status == 500 and "tick exploded" in out["error"]
+            status, out = await asyncio.wait_for(
+                http("POST", "/v1/simulate", body), timeout=30)
+            assert status == 500 and "tick exploded" in out["error"]
+        finally:
+            await srv.stop()
+        assert not srv._pump.is_alive()
+
+    asyncio.run(scenario())
+
+
 # ---------------------------------------------------------------------------
 # soak smoke (the CI job runs the full driver; this keeps it importable
 # and its assertions honest at pytest scale)

@@ -186,23 +186,25 @@ def test_spmv_chosen_tiles_are_vmem_feasible(n_pre, k, n_post, b):
 
 
 def test_spmv_wide_k_chunks_to_feasible_tiles():
-    """K beyond the one-hot kernel's full-row VMEM limit must be flagged
+    """K beyond the kernel's full-row VMEM limit must be flagged
     infeasible and split into chunks whose tiling fits (e.g. the row widths
-    FixedProbability produces at p=0.05, n_post=100k)."""
+    FixedProbability produces at p=0.2, n_post=100k)."""
     from repro.kernels.autotune import (V5E, choose_block_spmv,
                                         spmv_block_bytes)
     from repro.kernels.ell_spmv import feasible_k_chunk
-    wide = choose_block_spmv(10_000, 5000, 100_000, 1)
+    wide = choose_block_spmv(10_000, 20_000, 100_000, 1)
     assert not wide["feasible"]
-    kc, cfg = feasible_k_chunk(10_000, 5000, 100_000, 1)
-    assert kc < 5000 and cfg["feasible"]
+    kc, cfg = feasible_k_chunk(10_000, 20_000, 100_000, 1)
+    assert kc < 20_000 and cfg["feasible"]
     need = spmv_block_bytes(cfg["bp"], cfg["bn"], kc, 1) * V5E.double_buffer
     assert need <= V5E.vmem_bytes
 
 
 def test_spmv_pallas_wide_k_correct():
     """Interpret-mode end to end through the K-chunked launch path."""
-    n_pre, k, n_post, b = 24, 5000, 64, 2
+    from repro.kernels.ell_spmv import feasible_k_chunk
+    n_pre, k, n_post, b = 24, 20_000, 64, 2
+    assert feasible_k_chunk(n_pre, k, n_post, b)[0] < k
     g = RNG.standard_normal((n_pre, k)).astype(np.float32)
     idx = RNG.integers(0, n_post, (n_pre, k)).astype(np.int32)
     valid = RNG.random((n_pre, k)) < 0.5
@@ -238,3 +240,32 @@ def test_spmv_pallas_correct_with_autotuned_blocks():
                           n_post=n_post, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_tpu_limits_looked_up_by_device_kind():
+    """On a TPU the tiles follow the chip's device_kind and an unknown kind
+    is an error; off the TPU they are a v5e's, the chip they model."""
+    import types
+
+    from repro.kernels.autotune import (V5E, UnknownTPUError,
+                                        device_limits)
+    dev = lambda platform, kind: types.SimpleNamespace(
+        platform=platform, device_kind=kind)
+    assert device_limits(dev("tpu", "TPU v5 lite")) is V5E
+    assert device_limits(dev("cpu", "cpu")) is V5E
+    assert device_limits() is V5E                 # this CPU-only process
+    with pytest.raises(UnknownTPUError, match="TPU v99"):
+        device_limits(dev("tpu", "TPU v99"))
+
+
+def test_interpret_mode_on_tpu_is_refused(monkeypatch):
+    """REPRO_USE_PALLAS=interpret would run the kernels through the
+    interpreter on the chip: the dispatch refuses by name instead."""
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_USE_PALLAS", "interpret")
+    assert ops.backend() == "interpret"           # CPU: validation mode
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ops.InterpretOnTPUError, match="REPRO_USE_PALLAS"):
+        ops.backend()
+    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    assert ops.backend() == "pallas"

@@ -75,6 +75,24 @@ def test_span_nesting_and_chrome_export(tmp_path):
     assert doc["otherData"]["dropped_events"] == 0
 
 
+def test_jax_profiler_trace_raises_when_profiler_cannot_start(monkeypatch,
+                                                             tmp_path):
+    """A run that asked for a device trace must not finish without one."""
+    import jax.profiler
+
+    from repro.obs.profile import ProfilerUnavailable, jax_profiler_trace
+
+    def refuse(logdir, *a, **k):
+        raise RuntimeError("profiler already running")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    ran = []
+    with pytest.raises(ProfilerUnavailable, match="already running"):
+        with jax_profiler_trace(str(tmp_path)):
+            ran.append(True)
+    assert ran == []
+
+
 def test_span_records_even_when_body_raises():
     c = TraceCollector()
     with pytest.raises(RuntimeError):

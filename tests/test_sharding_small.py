@@ -91,3 +91,26 @@ def test_small_mesh_dryrun_subprocess():
     for arch, r in res.items():
         assert r["compiled"], arch
         assert r["has_collectives"], arch
+
+
+def test_compile_cache_follows_env_else_fixed_checkout_dir(monkeypatch,
+                                                           tmp_path):
+    """Entry points keep the persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says, setting no path of their own; without
+    it, in one fixed directory of the checkout."""
+    from repro.launch import compile_cache as CC
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(CC.CACHE_ENV, str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert CC.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None   # JAX reads it
+
+        monkeypatch.delenv(CC.CACHE_ENV)
+        path = CC.enable_compile_cache()
+        assert path == str(CC.DEFAULT_CACHE_DIR) == CC.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert Path(path).parent == Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

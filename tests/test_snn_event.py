@@ -133,6 +133,30 @@ def test_event_overflow_boundary():
         assert np.array_equal(np.asarray(at_cap), np.asarray(dense)), n_spk
 
 
+def test_event_spmv_under_vmap_matches_each_stream():
+    """Served streams (vmap) propagate as their offline runs do: a stream
+    past the capacity takes the dense fallback while its neighbours stay on
+    the event path, and each equals its unbatched call bit for bit."""
+    rng = np.random.default_rng(5)
+    n_pre, k, n_post, n_slots = 32, 5, 24, 4
+    post_ind = rng.integers(0, n_post, (n_pre, k)).astype(np.int32)
+    g = rng.standard_normal((n_pre, k)).astype(np.float32)
+    valid = rng.random((n_pre, k)) < 0.9
+    delay = np.where(valid, rng.integers(0, n_slots, (n_pre, k)), 0)
+    ell = F.triple_to_ell(post_ind, g, valid, n_post,
+                          delay=delay.astype(np.int32))
+    spikes = np.zeros((3, n_pre), np.float32)
+    for s, n_spk in enumerate((4, 11, 0)):       # capacity 10
+        spikes[s, rng.choice(n_pre, n_spk, replace=False)] = 1.0
+    spikes = jnp.asarray(spikes)
+    for fn in (lambda s: kops.ell_spmv_event(ell, s, 10),
+               lambda s: kops.ell_spmv_event_delay(ell, s, n_slots, 10),
+               lambda s: kops.ell_spmv(ell, s)):
+        batched = np.asarray(jax.jit(jax.vmap(fn))(spikes))
+        for s in range(spikes.shape[0]):
+            assert np.array_equal(batched[s], np.asarray(fn(spikes[s]))), s
+
+
 def test_fused_delay_matches_masked_pass_loop():
     """The fused kernel replaces S+1 masked single-delay passes; per slot
     it must reproduce each masked pass bit for bit (random float weights)."""
