@@ -324,8 +324,9 @@ class ShardedEngine:
         stim = stim or {}
         net, dt, ax = self.net, self.dt, self.axis
         d = jax.lax.axis_index(ax)
-        key, *subkeys = jax.random.split(state.key,
-                                         1 + 2 * len(net.populations))
+        with jax.named_scope("rng"):
+            key, *subkeys = jax.random.split(state.key,
+                                             1 + 2 * len(net.populations))
         subkeys = iter(subkeys)
 
         # 0. spike exchange, bit-packed (GeNN's 32x spike bitmask): each
@@ -336,110 +337,115 @@ class ShardedEngine:
         full_spikes = {}
         D = self.n_shards
         for name in sorted({g.pre for g in net.synapses}):
-            seg = self._shard[name]
-            words = BM.pack_spikes(state.spikes[name])
-            fw = jax.lax.all_gather(words, ax, tiled=True)
-            full = BM.unpack_segments(fw.reshape(D, BM.words_for(seg)), seg)
-            full_spikes[name] = full[: net.populations[name].n]
+            with jax.named_scope("exchange"):
+                seg = self._shard[name]
+                words = BM.pack_spikes(state.spikes[name])
+                fw = jax.lax.all_gather(words, ax, tiled=True)
+                full = BM.unpack_segments(
+                    fw.reshape(D, BM.words_for(seg)), seg)
+                full_spikes[name] = full[: net.populations[name].n]
 
         # 1. synaptic propagation into the local post shard --------------
         isyn = {name: jnp.zeros((self._shard[name],), jnp.float32)
                 for name in net.populations}
         new_syn = dict(state.syn)
         for g in net.synapses:
-            gs = jnp.asarray(gscales.get(g.name, 1.0), jnp.float32)
-            blk = blocks[g.name]
-            if "dense" in blk:
-                ell_l, dense_l = None, blk["dense"]
-                # a local ELL stand-in keeps post-side shapes consistent
-                ell_l = F.ELLSynapses(
-                    g=jnp.zeros((g.ell.n_pre, 1), jnp.float32),
-                    post_ind=jnp.zeros((g.ell.n_pre, 1), jnp.int32),
-                    valid=jnp.zeros((g.ell.n_pre, 1), bool),
-                    n_post=self._shard[g.post])
-            else:
-                ell_l = F.ELLSynapses(g=blk["g"], post_ind=blk["post"],
-                                      valid=blk["valid"],
-                                      n_post=self._shard[g.post],
-                                      delay=blk.get("delay"))
-                dense_l = None
-            v_post = state.neurons[g.post].get("V")
-            new_pre_local = None
-            pre_arg = None
-            if g.wum.pre_state:
-                # wu_pre shards along the PRE axis: advance the local
-                # trace segment (the elementwise pre_step commutes with
-                # slicing; padded lanes never spike) and gather the full
-                # vector only when learn code actually reads it — a
-                # per-step transient, never replicated persistent state
-                new_pre_local = state.syn[g.name].wu_pre
-                if g._wu.pre_step is not None:
-                    new_pre_local = g._wu.pre_step(
-                        state.syn[g.name].wu_pre, g.wum.params,
-                        {"dt": dt, "t": state.t,
-                         "delay": jnp.float32(g.delay_steps),
-                         "pre_spike":
-                             state.spikes[g.pre].astype(jnp.float32)})
-                pre_arg = {}
-                if g._wu.learn is not None:
-                    pre_arg = {
-                        k: jax.lax.all_gather(
-                            v, ax, tiled=True)[: g.ell.n_pre]
-                        for k, v in new_pre_local.items()}
-            s_new, cur = g.step(
-                state.syn[g.name], full_spikes[g.pre], gs, dt,
-                v_post=v_post, post_spikes=state.spikes[g.post], t=state.t,
-                conn=LocalConnectivity(ell=ell_l, dense=dense_l),
-                pre_traces=pre_arg)
-            if new_pre_local is not None:
-                s_new = s_new.__class__(
-                    psm=s_new.psm, wu_pre=new_pre_local,
-                    wu_post=s_new.wu_post, g=s_new.g, syn=s_new.syn,
-                    dendritic=s_new.dendritic, cursor=s_new.cursor)
-            new_syn[g.name] = s_new
-            isyn[g.post] = isyn[g.post] + cur
+            with jax.named_scope(f"prop/{g.name}"):
+                gs = jnp.asarray(gscales.get(g.name, 1.0), jnp.float32)
+                blk = blocks[g.name]
+                if "dense" in blk:
+                    ell_l, dense_l = None, blk["dense"]
+                    # a local ELL stand-in keeps post-side shapes consistent
+                    ell_l = F.ELLSynapses(
+                        g=jnp.zeros((g.ell.n_pre, 1), jnp.float32),
+                        post_ind=jnp.zeros((g.ell.n_pre, 1), jnp.int32),
+                        valid=jnp.zeros((g.ell.n_pre, 1), bool),
+                        n_post=self._shard[g.post])
+                else:
+                    ell_l = F.ELLSynapses(g=blk["g"], post_ind=blk["post"],
+                                          valid=blk["valid"],
+                                          n_post=self._shard[g.post],
+                                          delay=blk.get("delay"))
+                    dense_l = None
+                v_post = state.neurons[g.post].get("V")
+                new_pre_local = None
+                pre_arg = None
+                if g.wum.pre_state:
+                    # wu_pre shards along the PRE axis: advance the local
+                    # trace segment (the elementwise pre_step commutes with
+                    # slicing; padded lanes never spike) and gather the full
+                    # vector only when learn code actually reads it — a
+                    # per-step transient, never replicated persistent state
+                    new_pre_local = state.syn[g.name].wu_pre
+                    if g._wu.pre_step is not None:
+                        new_pre_local = g._wu.pre_step(
+                            state.syn[g.name].wu_pre, g.wum.params,
+                            {"dt": dt, "t": state.t,
+                             "delay": jnp.float32(g.delay_steps),
+                             "pre_spike":
+                                 state.spikes[g.pre].astype(jnp.float32)})
+                    pre_arg = {}
+                    if g._wu.learn is not None:
+                        with jax.named_scope("exchange"):
+                            pre_arg = {
+                                k: jax.lax.all_gather(
+                                    v, ax, tiled=True)[: g.ell.n_pre]
+                                for k, v in new_pre_local.items()}
+                s_new, cur = g.step(
+                    state.syn[g.name], full_spikes[g.pre], gs, dt,
+                    v_post=v_post, post_spikes=state.spikes[g.post], t=state.t,
+                    conn=LocalConnectivity(ell=ell_l, dense=dense_l),
+                    pre_traces=pre_arg)
+                if new_pre_local is not None:
+                    s_new = s_new.__class__(
+                        psm=s_new.psm, wu_pre=new_pre_local,
+                        wu_post=s_new.wu_post, g=s_new.g, syn=s_new.syn,
+                        dendritic=s_new.dendritic, cursor=s_new.cursor)
+                new_syn[g.name] = s_new
+                isyn[g.post] = isyn[g.post] + cur
 
         # 2+3. neuron updates on the local shard -------------------------
         new_neurons, new_spikes = {}, {}
         new_prev = dict(state.prev_above)
         finite = state.finite
         for name, pop in net.populations.items():
-            k_in, k_rand = next(subkeys), next(subkeys)
-            S = self._shard[name]
-            lane = d * S + jnp.arange(S)
-            lane_valid = lane < pop.n
-            cur = isyn[name]
-            if pop.input_fn is not None:
-                # full-size draw + slice: bit-identical to the unsharded
-                # path (the key consumes the same stream regardless of D)
-                full = pop.input_fn(k_in, state.t, pop.n)
-                full = jnp.pad(full, (0, self._npad[name] - pop.n))
-                cur = cur + jax.lax.dynamic_slice(full, (d * S,), (S,))
-            if name in stim:
-                # stim arrives zero-padded and sharded along the neuron
-                # axis (see _pad_stim): the local segment adds directly —
-                # bit-identical to the old replicated draw + slice, with
-                # 1/D the per-device footprint
-                cur = cur + jnp.asarray(stim[name], jnp.float32)
-            params = dict(self._scalar_params[name])
-            params.update(pn_params[name])
-            ext = {"Isyn": cur, "dt": jnp.float32(dt), "t": state.t}
-            if pop.model.needs_rand:
-                full = jax.random.uniform(k_rand, (pop.n,))
-                full = jnp.pad(full, (0, self._npad[name] - pop.n))
-                ext["rand"] = jax.lax.dynamic_slice(full, (d * S,), (S,))
-            ns, above = self._updates[name](state.neurons[name], params,
-                                           ext)
-            if pop.edge_spikes:
-                spk = above & ~state.prev_above[name]
-                new_prev[name] = above
-            else:
-                spk = above
-            new_neurons[name] = ns
-            new_spikes[name] = spk & lane_valid
-            for arr in ns.values():
-                finite = finite & jnp.all(
-                    jnp.isfinite(jnp.where(lane_valid, arr, 0.0)))
+            with jax.named_scope(f"neuron/{name}"):
+                k_in, k_rand = next(subkeys), next(subkeys)
+                S = self._shard[name]
+                lane = d * S + jnp.arange(S)
+                lane_valid = lane < pop.n
+                cur = isyn[name]
+                if pop.input_fn is not None:
+                    # full-size draw + slice: bit-identical to the unsharded
+                    # path (the key consumes the same stream regardless of D)
+                    full = pop.input_fn(k_in, state.t, pop.n)
+                    full = jnp.pad(full, (0, self._npad[name] - pop.n))
+                    cur = cur + jax.lax.dynamic_slice(full, (d * S,), (S,))
+                if name in stim:
+                    # stim arrives zero-padded and sharded along the neuron
+                    # axis (see _pad_stim): the local segment adds directly —
+                    # bit-identical to the old replicated draw + slice, with
+                    # 1/D the per-device footprint
+                    cur = cur + jnp.asarray(stim[name], jnp.float32)
+                params = dict(self._scalar_params[name])
+                params.update(pn_params[name])
+                ext = {"Isyn": cur, "dt": jnp.float32(dt), "t": state.t}
+                if pop.model.needs_rand:
+                    full = jax.random.uniform(k_rand, (pop.n,))
+                    full = jnp.pad(full, (0, self._npad[name] - pop.n))
+                    ext["rand"] = jax.lax.dynamic_slice(full, (d * S,), (S,))
+                ns, above = self._updates[name](state.neurons[name], params,
+                                               ext)
+                if pop.edge_spikes:
+                    spk = above & ~state.prev_above[name]
+                    new_prev[name] = above
+                else:
+                    spk = above
+                new_neurons[name] = ns
+                new_spikes[name] = spk & lane_valid
+                for arr in ns.values():
+                    finite = finite & jnp.all(
+                        jnp.isfinite(jnp.where(lane_valid, arr, 0.0)))
 
         new_state = SimState(
             neurons=new_neurons, spikes=new_spikes, prev_above=new_prev,
@@ -494,9 +500,10 @@ class ShardedEngine:
             return state
         elapsed = jnp.int32(jnp.round(state.t / jnp.float32(self.dt)))
         for cu in self._scheduled:
-            trig = (elapsed % cu.every) == 0
-            state = self._apply_custom_local(state, cu, trig, blocks,
-                                             pn_params)
+            with jax.named_scope(f"custom/{cu.name}"):
+                trig = (elapsed % cu.every) == 0
+                state = self._apply_custom_local(state, cu, trig, blocks,
+                                                 pn_params)
         return state
 
     def _group_reduce_local(self, op, val, blk, axis, denom_all: float,
@@ -697,28 +704,33 @@ class ShardedEngine:
                            blocks, gate=None):
         out = dict(bufs)
         for p in self.probes:
-            base = PR.probe_base(p, start)
-            active, slot = PR.sample_slot(p, start, base, i, caps[p.name])
-            if gate is not None:
-                active = active & gate
-            val = self._probe_local_value(p, state, spikes, blocks)
-            if PR.is_packed(p):
-                val = BM.pack_spikes(val)
-            out[p.name] = PR.write_sample(bufs[p.name], slot, active, val)
+            with jax.named_scope("probe"):
+                base = PR.probe_base(p, start)
+                active, slot = PR.sample_slot(p, start, base, i,
+                                              caps[p.name])
+                if gate is not None:
+                    active = active & gate
+                val = self._probe_local_value(p, state, spikes, blocks)
+                if PR.is_packed(p):
+                    val = BM.pack_spikes(val)
+                out[p.name] = PR.write_sample(bufs[p.name], slot, active,
+                                              val)
         return out
 
     def _probe_finalize_local(self, bufs, caps, start, n_eff,
                               serving: bool = False):
         data, counts = {}, {}
         for p in self.probes:
-            d, counts[p.name] = PR.finalize(
-                bufs[p.name], start, n_eff, p, caps[p.name],
-                use_window=not serving)
-            if PR.is_packed(p):
-                # unpack to the local shard width while still inside
-                # shard_map, so the exit gather/crop contract is unchanged
-                d = BM.unpack_rows(d, self._shard[p.target])
-            data[p.name] = d
+            with jax.named_scope("probe"):
+                d, counts[p.name] = PR.finalize(
+                    bufs[p.name], start, n_eff, p, caps[p.name],
+                    use_window=not serving)
+                if PR.is_packed(p):
+                    # unpack to the local shard width while still inside
+                    # shard_map, so the exit gather/crop contract is
+                    # unchanged
+                    d = BM.unpack_rows(d, self._shard[p.target])
+                data[p.name] = d
         return data, counts
 
     def _probe_out_specs(self, lead=()):
@@ -817,10 +829,11 @@ class ShardedEngine:
                                                spk, blocks)
                 out = spk if record_raster else None
                 if mon is not None:
-                    hstate = HE.accumulate(
-                        mon, hstate, self._health_counts_local(spk),
-                        self._health_ok_local(st2, blocks), self.dt,
-                        self._pop_sizes)
+                    with jax.named_scope("health"):
+                        hstate = HE.accumulate(
+                            mon, hstate, self._health_counts_local(spk),
+                            self._health_ok_local(st2, blocks), self.dt,
+                            self._pop_sizes)
                     return (st2, counts, bufs, hstate), out
                 return (st2, counts, bufs), out
 
@@ -871,6 +884,29 @@ class ShardedEngine:
         """Scan n_steps under shard_map; spike statistics match the
         single-device Simulator bit for bit.  stim: population ->
         [n_steps, n] external currents (full-size; sliced per shard)."""
+        fn, args, compiled = self._run_program(n_steps, gscales, state,
+                                               record_raster, stim)
+        with trace.span("run", model=self.net.name, n_steps=n_steps,
+                        sharded=True, compile=compiled):
+            out = fn(*args)
+        st2, counts, raster, pdata, pcounts = out[:5]
+        health = out[5] if self.monitor is not None else None
+        pops = self.net.populations
+        counts = {k: v[: pops[k].n] for k, v in counts.items()}
+        t_sec = n_steps * self.dt * 1e-3
+        rates = {k: jnp.mean(v) / t_sec for k, v in counts.items()}
+        if record_raster:
+            raster = {k: v[:, : pops[k].n] for k, v in raster.items()}
+        rec = Recordings(data=self._crop_probe_data(pdata), counts=pcounts)
+        return RunResult(state=st2, spike_counts=counts, rates_hz=rates,
+                         finite=st2.finite,
+                         raster=raster if record_raster else None,
+                         recordings=rec, health=health)
+
+    def _run_program(self, n_steps: int, gscales, state, record_raster,
+                     stim):
+        """The cached shard_map'd run program for these keys, its
+        arguments, and whether this call created it."""
         gscales = dict(gscales or {})
         self._validate_gscales(gscales)
         self._validate_stim(stim)
@@ -886,23 +922,8 @@ class ShardedEngine:
                                                         record_raster,
                                                         stim_keys)
         vals = tuple(jnp.asarray(gscales[k], jnp.float32) for k in keys)
-        with trace.span("run", model=self.net.name, n_steps=n_steps,
-                        sharded=True, compile=compiled):
-            out = self._run_cache[cache_key](
-                state, self._blocks, self._pn_params, vals, stim)
-        st2, counts, raster, pdata, pcounts = out[:5]
-        health = out[5] if self.monitor is not None else None
-        pops = self.net.populations
-        counts = {k: v[: pops[k].n] for k, v in counts.items()}
-        t_sec = n_steps * self.dt * 1e-3
-        rates = {k: jnp.mean(v) / t_sec for k, v in counts.items()}
-        if record_raster:
-            raster = {k: v[:, : pops[k].n] for k, v in raster.items()}
-        rec = Recordings(data=self._crop_probe_data(pdata), counts=pcounts)
-        return RunResult(state=st2, spike_counts=counts, rates_hz=rates,
-                         finite=st2.finite,
-                         raster=raster if record_raster else None,
-                         recordings=rec, health=health)
+        args = (state, self._blocks, self._pn_params, vals, stim)
+        return self._run_cache[cache_key], args, compiled
 
     def _make_step(self, keys: Tuple[str, ...],
                    stim_keys: Tuple[str, ...] = ()):
@@ -1094,10 +1115,11 @@ class ShardedEngine:
                                                    blocks, gate=act)
                     out = spk if record_raster else None
                     if mon is not None:
-                        hstate = HE.accumulate(
-                            mon, hstate, self._health_counts_local(spk),
-                            self._health_ok_local(st2, blocks), self.dt,
-                            self._pop_sizes, gate=act)
+                        with jax.named_scope("health"):
+                            hstate = HE.accumulate(
+                                mon, hstate, self._health_counts_local(spk),
+                                self._health_ok_local(st2, blocks), self.dt,
+                                self._pop_sizes, gate=act)
                         return (st2, counts, bufs, hstate), out
                     return (st2, counts, bufs), out
 

@@ -235,8 +235,9 @@ class Simulator:
         self._validate_stim(stim)
         gscales = gscales or {}
         stim = stim or {}
-        key, *subkeys = jax.random.split(state.key,
-                                         1 + 2 * len(net.populations))
+        with jax.named_scope("rng"):
+            key, *subkeys = jax.random.split(state.key,
+                                             1 + 2 * len(net.populations))
         subkeys = iter(subkeys)
 
         # 1. synaptic propagation (last step's spikes) ------------------
@@ -244,38 +245,41 @@ class Simulator:
                 for name, pop in net.populations.items()}
         new_syn = dict(state.syn)
         for g in net.synapses:
-            gs = jnp.asarray(gscales.get(g.name, 1.0), jnp.float32)
-            v_post = state.neurons[g.post].get("V")
-            s_new, cur = g.step(state.syn[g.name], state.spikes[g.pre], gs,
-                                dt, v_post=v_post,
-                                post_spikes=state.spikes[g.post], t=state.t)
-            new_syn[g.name] = s_new
-            isyn[g.post] = isyn[g.post] + cur
+            with jax.named_scope(f"prop/{g.name}"):
+                gs = jnp.asarray(gscales.get(g.name, 1.0), jnp.float32)
+                v_post = state.neurons[g.post].get("V")
+                s_new, cur = g.step(state.syn[g.name], state.spikes[g.pre],
+                                    gs, dt, v_post=v_post,
+                                    post_spikes=state.spikes[g.post],
+                                    t=state.t)
+                new_syn[g.name] = s_new
+                isyn[g.post] = isyn[g.post] + cur
 
         # 2+3. neuron updates via generated code ------------------------
         new_neurons, new_spikes, new_prev = {}, {}, dict(state.prev_above)
         finite = state.finite
         for name, pop in net.populations.items():
-            k_in, k_rand = next(subkeys), next(subkeys)
-            cur = isyn[name]
-            if pop.input_fn is not None:
-                cur = cur + pop.input_fn(k_in, state.t, pop.n)
-            if name in stim:
-                cur = cur + jnp.asarray(stim[name], jnp.float32)
-            ext = {"Isyn": cur, "dt": jnp.float32(dt), "t": state.t}
-            if pop.model.needs_rand:
-                ext["rand"] = jax.random.uniform(k_rand, (pop.n,))
-            ns, above = self._updates[name](state.neurons[name], pop.params,
-                                            ext)
-            if pop.edge_spikes:
-                spk = above & ~state.prev_above[name]
-                new_prev[name] = above
-            else:
-                spk = above
-            new_neurons[name] = ns
-            new_spikes[name] = spk
-            for arr in ns.values():
-                finite = finite & jnp.all(jnp.isfinite(arr))
+            with jax.named_scope(f"neuron/{name}"):
+                k_in, k_rand = next(subkeys), next(subkeys)
+                cur = isyn[name]
+                if pop.input_fn is not None:
+                    cur = cur + pop.input_fn(k_in, state.t, pop.n)
+                if name in stim:
+                    cur = cur + jnp.asarray(stim[name], jnp.float32)
+                ext = {"Isyn": cur, "dt": jnp.float32(dt), "t": state.t}
+                if pop.model.needs_rand:
+                    ext["rand"] = jax.random.uniform(k_rand, (pop.n,))
+                ns, above = self._updates[name](state.neurons[name],
+                                                pop.params, ext)
+                if pop.edge_spikes:
+                    spk = above & ~state.prev_above[name]
+                    new_prev[name] = above
+                else:
+                    spk = above
+                new_neurons[name] = ns
+                new_spikes[name] = spk
+                for arr in ns.values():
+                    finite = finite & jnp.all(jnp.isfinite(arr))
 
         new_state = SimState(
             neurons=new_neurons, spikes=new_spikes, prev_above=new_prev,
@@ -295,8 +299,9 @@ class Simulator:
             return state
         elapsed = jnp.int32(jnp.round(state.t / jnp.float32(self.dt)))
         for cu in self._scheduled:
-            trig = (elapsed % cu.every) == 0
-            state = self._apply_custom(state, cu, trig)
+            with jax.named_scope(f"custom/{cu.name}"):
+                trig = (elapsed % cu.every) == 0
+                state = self._apply_custom(state, cu, trig)
         return state
 
     def _apply_custom(self, state: SimState, cu, trig) -> SimState:
@@ -385,24 +390,29 @@ class Simulator:
         """One post-step sampling pass (strided ring write per probe)."""
         out = dict(bufs)
         for p in self.probes:
-            base = PR.probe_base(p, start)
-            active, slot = PR.sample_slot(p, start, base, i, caps[p.name])
-            if gate is not None:
-                active = active & gate
-            val = PR.host_sample(p, self._groups, state, spikes)
-            if PR.is_packed(p):
-                val = BM.pack_spikes(val)
-            out[p.name] = PR.write_sample(bufs[p.name], slot, active, val)
+            with jax.named_scope("probe"):
+                base = PR.probe_base(p, start)
+                active, slot = PR.sample_slot(p, start, base, i,
+                                              caps[p.name])
+                if gate is not None:
+                    active = active & gate
+                val = PR.host_sample(p, self._groups, state, spikes)
+                if PR.is_packed(p):
+                    val = BM.pack_spikes(val)
+                out[p.name] = PR.write_sample(bufs[p.name], slot, active,
+                                              val)
         return out
 
     def _probe_finalize(self, bufs, caps, start, n_eff,
                         serving: bool = False) -> Recordings:
         data, counts = {}, {}
         for p in self.probes:
-            d, counts[p.name] = PR.finalize(
-                bufs[p.name], start, n_eff, p, caps[p.name],
-                use_window=not serving)
-            data[p.name] = BM.unpack_rows(d, p.n) if PR.is_packed(p) else d
+            with jax.named_scope("probe"):
+                d, counts[p.name] = PR.finalize(
+                    bufs[p.name], start, n_eff, p, caps[p.name],
+                    use_window=not serving)
+                data[p.name] = (BM.unpack_rows(d, p.n) if PR.is_packed(p)
+                                else d)
         return Recordings(data=data, counts=counts)
 
     # ------------------------------------------------------------------
@@ -464,9 +474,10 @@ class Simulator:
             bufs = self._probe_write(bufs, caps, start, i, st2, spk)
             out = spk if record_raster else None
             if mon is not None:
-                hstate = HE.accumulate(mon, hstate, self._health_counts(spk),
-                                       self._health_ok(st2), self.dt,
-                                       self._pop_sizes)
+                with jax.named_scope("health"):
+                    hstate = HE.accumulate(
+                        mon, hstate, self._health_counts(spk),
+                        self._health_ok(st2), self.dt, self._pop_sizes)
                 return (st2, counts, bufs, hstate), out
             return (st2, counts, bufs), out
 
@@ -586,10 +597,11 @@ class Simulator:
                                          spk, gate=act)
                 out = spk if record_raster else None
                 if mon is not None:
-                    hstate = HE.accumulate(
-                        mon, hstate, self._health_counts(spk),
-                        self._health_ok(st2), self.dt, self._pop_sizes,
-                        gate=act)
+                    with jax.named_scope("health"):
+                        hstate = HE.accumulate(
+                            mon, hstate, self._health_counts(spk),
+                            self._health_ok(st2), self.dt, self._pop_sizes,
+                            gate=act)
                     return (st2, counts, bufs, hstate), out
                 return (st2, counts, bufs), out
 

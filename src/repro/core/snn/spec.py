@@ -47,7 +47,6 @@ from repro.core.snn.probes import ProbeSpec, Recordings
 from repro.core.snn.custom_updates import CustomUpdateSpec
 from repro.core.snn.simulator import RunResult, SimState, Simulator
 from repro.core.snn.synapses import PROPAGATIONS, Pulse, SynapseGroup
-from repro.kernels import autotune as AT
 from repro.obs import trace
 from repro.sparse import formats as F
 
@@ -774,14 +773,6 @@ class ModelSpec:
             probes = PR.resolve_probes(self.probes, net)
             custom = CU.resolve_custom_updates(self.custom_updates, net)
 
-        # audit the tile the ELL-spmv kernel would pick for every group
-        # (choose_block_spmv records an instant trace event per decision:
-        # chosen tile, occupancy estimate, VMEM footprint — auditable even
-        # for groups the representation choice routed to the dense path)
-        for g in net.synapses:
-            AT.choose_block_spmv(g.ell.n_pre, g.ell.max_conn, g.ell.n_post,
-                                 b=1, tag=f"{g.name}:{g.representation}")
-
         engine = None
         if mesh is not None:
             from repro.core.snn.engine import ShardedEngine
@@ -806,6 +797,16 @@ class SweepResult:
     finite: jax.Array                      # [n_candidates] bool
     spike_counts: Dict[str, jax.Array]     # pop -> [n_candidates, n]
     recordings: object = None              # Recordings, leading cand. axis
+
+
+def _cache_drops_metadata() -> bool:
+    """JAX's persistent compile cache is on and its key leaves out the
+    programs' metadata, so an executable loaded from it may carry the op
+    names of another source."""
+    cfg = jax.config
+    return (cfg.jax_compilation_cache_dir is not None
+            and cfg.jax_enable_compilation_cache
+            and not cfg.jax_compilation_cache_include_metadata_in_key)
 
 
 class CompiledModel:
@@ -932,6 +933,16 @@ class CompiledModel:
         if self.engine is not None:
             return self.engine.run(n_steps, gscales, state, record_raster,
                                    stim=stim)
+        fn, args, compiled = self._run_program(n_steps, gscales, state,
+                                               record_raster, stim)
+        with trace.span("run", model=self.spec.name, n_steps=n_steps,
+                        sharded=False, compile=compiled):
+            return fn(*args)
+
+    def _run_program(self, n_steps: int, gscales, state, record_raster,
+                     stim):
+        """The cached jitted run program for these keys, its arguments, and
+        whether this call created it."""
         if state is None:
             state = self.init_state()
         keys = tuple(sorted(gscales))
@@ -949,10 +960,38 @@ class CompiledModel:
 
             self._run_cache[cache_key] = _run
         vals = tuple(gscales[k] for k in keys)
-        with trace.span("run", model=self.spec.name, n_steps=n_steps,
-                        sharded=False, compile=compiled):
-            return self._run_cache[cache_key](
-                self.simulator.connectivity(), state, vals, stim)
+        args = (self.simulator.connectivity(), state, vals, stim)
+        return self._run_cache[cache_key], args, compiled
+
+    def run_hlo(self, n_steps: int,
+                gscales: Optional[Mapping[str, jax.Array]] = None,
+                state: Optional[SimState] = None,
+                record_raster: bool = False,
+                stim: Optional[Mapping[str, jax.Array]] = None) -> str:
+        """The optimized HLO text of the program ``run`` executes with the
+        same arguments (``compile().as_text()`` of the cached program;
+        after such a ``run`` nothing is compiled again).  Each
+        instruction's ``metadata={op_name=...}`` carries the step's named
+        scopes (repro.obs.trace lists them): what an operator reads to map a
+        profile's device ops to the layers of the step.
+
+        Raises RuntimeError where JAX's persistent compile cache is on but
+        keys executables without their metadata: one loaded from it may
+        name the scopes of another source
+        (``repro.launch.compile_cache.enable_compile_cache`` keys them with
+        it)."""
+        if _cache_drops_metadata():
+            raise RuntimeError(
+                "the persistent compile cache is keyed without metadata, so "
+                "a cached executable may carry stale scope names: set "
+                "jax_compilation_cache_include_metadata_in_key (as "
+                "repro.launch.compile_cache.enable_compile_cache does)")
+        gscales = self._norm_gscales(gscales)
+        stim = self._norm_stim(stim)
+        backend = self.engine if self.engine is not None else self
+        fn, args, _ = backend._run_program(n_steps, gscales, state,
+                                           record_raster, stim)
+        return fn.lower(*args).compile().as_text()
 
     def sweep_gscale(self, group: Union[str, Sequence[str]],
                      values, n_steps: int,

@@ -380,7 +380,8 @@ class SynapseGroup:
         (dense full-matrix pass vs event-driven row gathering)."""
         if self.propagation_mode == "event":
             return kops.ell_spmv_event(ell, spk, self.event_capacity)
-        return kops.ell_spmv(ell, spk)
+        with jax.named_scope("dense"):
+            return kops.ell_spmv(ell, spk)
 
     def _raw_current(self, spikes: jax.Array, gscale: jax.Array,
                      g: Optional[jax.Array], syn: Dict[str, jax.Array],
@@ -393,7 +394,8 @@ class SynapseGroup:
         spk = jnp.asarray(spikes, jnp.float32)
         if (self.wum.is_static_pulse and g is None
                 and self.representation == "dense"):
-            out = sparse_ops.accumulate_dense(dense, spk)
+            with jax.named_scope("dense"):
+                out = sparse_ops.accumulate_dense(dense, spk)
         else:
             out = self._spmv(self._effective_ell(g, syn, externals, ell), spk)
         return self.sign * gscale * out
@@ -413,7 +415,8 @@ class SynapseGroup:
             out = kops.ell_spmv_event_delay(eff, spk, self.ring_slots,
                                             self.event_capacity)
         else:
-            out = kops.ell_spmv_delay(eff, spk, self.ring_slots)
+            with jax.named_scope("dense"):
+                out = kops.ell_spmv_delay(eff, spk, self.ring_slots)
         return self.sign * gscale * out
 
     def _resolve_conn(self, conn: Optional[LocalConnectivity],

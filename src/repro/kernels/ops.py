@@ -147,17 +147,27 @@ def _compact_rows(ell, spikes: jax.Array, capacity: int):
     return ell_c, spk_c, count
 
 
+def _scoped(name: str, fn, *args):
+    """A ``lax.cond`` branch: ``fn(*args)`` under the named scope
+    ``name`` (``event`` within capacity, ``fallback`` over it)."""
+    def branch():
+        with jax.named_scope(name):
+            return fn(*args)
+    return branch
+
+
 @_per_sample
 def ell_spmv_event(ell, spikes: jax.Array, capacity: int) -> jax.Array:
     """Event-driven spmv: gather only the spiking rows (fixed capacity);
     more than `capacity` simultaneous spikes falls back to the dense pass.
     spikes [n_pre] -> currents [n_post], bit-exact vs ell_spmv."""
-    ell_c, spk_c, count = _compact_rows(ell, spikes, capacity)
+    with jax.named_scope("compact"):
+        ell_c, spk_c, count = _compact_rows(ell, spikes, capacity)
     spk = jnp.asarray(spikes, jnp.float32)
     return jax.lax.cond(
         count <= capacity,
-        lambda: ell_spmv(ell_c, spk_c),
-        lambda: ell_spmv(ell, spk))
+        _scoped("event", ell_spmv, ell_c, spk_c),
+        _scoped("fallback", ell_spmv, ell, spk))
 
 
 @_per_sample
@@ -166,12 +176,13 @@ def ell_spmv_event_delay(ell, spikes: jax.Array, n_slots: int,
     """Event-driven fused delay-scatter: spikes [n_pre] ->
     [n_slots, n_post], bit-exact vs ell_spmv_delay; overflow falls back to
     the dense fused pass."""
-    ell_c, spk_c, count = _compact_rows(ell, spikes, capacity)
+    with jax.named_scope("compact"):
+        ell_c, spk_c, count = _compact_rows(ell, spikes, capacity)
     spk = jnp.asarray(spikes, jnp.float32)
     return jax.lax.cond(
         count <= capacity,
-        lambda: ell_spmv_delay(ell_c, spk_c, n_slots),
-        lambda: ell_spmv_delay(ell, spk, n_slots))
+        _scoped("event", ell_spmv_delay, ell_c, spk_c, n_slots),
+        _scoped("fallback", ell_spmv_delay, ell, spk, n_slots))
 
 
 # -- fused neuron updates -----------------------------------------------------

@@ -20,6 +20,47 @@ Timestamps are microseconds relative to the collector's epoch
 (``time.perf_counter_ns`` at construction), which is what the Chrome trace
 viewer expects (``ts``/``dur`` in µs).  Nesting is implicit: the viewer
 reconstructs the span tree from ts/dur containment per ``tid``.
+
+Profiler spans.  Each span also opens ``jax.profiler.TraceAnnotation``
+named ``"repro." + name`` (``repro.build``, ``repro.run``,
+``repro.serve_chunk``, ...), so under ``jax.profiler.start_trace`` the
+program's spans land on the profiler's host plane, on the device trace's
+clock; outside a profiler session the annotation costs next to nothing.
+Every span's args gain ``compiles``: the number of XLA backend compiles
+(``/jax/core/compile/backend_compile_duration`` events) its thread made
+during the span, so a ``run`` that recompiled says so whatever the
+Python-side ``compile`` flag thinks.  A persistent-cache hit is no compile.
+
+Named scopes.  The simulation step names its layers with
+``jax.named_scope``; the names reach the optimized HLO as each
+instruction's ``metadata={op_name=".../<scope>/..."}`` and nothing else
+(the program is the same instruction for instruction).  The vocabulary:
+
+=========================  ==============================================
+scope                      around
+=========================  ==============================================
+``prop/<group>``           one synapse group's ``SynapseGroup.step``:
+                           propagation, postsynaptic model, weight update
+``prop/<group>/compact``   event path: gathering the spiking rows
+                           (``kernels.ops._compact_rows``)
+``prop/<group>/event``     event path: the scatter of the compacted rows
+                           (the ``lax.cond`` branch within capacity)
+``prop/<group>/fallback``  event path: the full pass when more rows fire
+                           than the capacity holds (the other branch)
+``prop/<group>/dense``     the accumulation of a group in dense mode
+``neuron/<pop>``           one population's input, stimulus, generated
+                           update, spike edge detection and NaN fold
+``rng``                    the step's key split
+``custom/<name>``          one scheduled custom update
+``probe``                  probe sampling and the probes' finalisation
+``health``                 the health monitor's per-step accumulation
+``exchange``               the sharded engine's spike and pre-trace
+                           all-gathers
+=========================  ==============================================
+
+JAX adds segments of its own between them (``while/body``, ``cond``,
+``branch_1_fun``, ...); a reader keeps the vocabulary's segments alone.
+``CompiledModel.run_hlo`` returns the optimized HLO text of a run program.
 """
 from __future__ import annotations
 
@@ -30,6 +71,8 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
+
+import jax
 
 __all__ = [
     "TraceCollector",
@@ -44,6 +87,23 @@ __all__ = [
 ]
 
 _DEFAULT_CAP = 65536
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+ANNOTATION_PREFIX = "repro."
+
+_compiles = threading.local()
+
+
+def _on_compile_event(event: str, duration: float, **_: Any) -> None:
+    if event == BACKEND_COMPILE:
+        _compiles.n = _compile_count() + 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def _compile_count() -> int:
+    """XLA backend compiles this thread has made since the import."""
+    return getattr(_compiles, "n", 0)
 
 
 def _jsonable(v: Any) -> Any:
@@ -84,13 +144,16 @@ class TraceCollector:
 
     @contextmanager
     def span(self, name: str, **args: Any) -> Iterator[None]:
-        """Record a complete ("X") event covering the with-block."""
+        """Record a complete ("X") event covering the with-block, with the
+        backend compiles it made as ``args["compiles"]``; the block is also
+        a ``repro.<name>`` span of the jax profiler."""
         if not self.enabled:
             yield
             return
-        t0 = self._now_us()
+        t0, c0 = self._now_us(), _compile_count()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name):
+                yield
         finally:
             self._append({
                 "name": name,
@@ -99,7 +162,8 @@ class TraceCollector:
                 "dur": self._now_us() - t0,
                 "pid": os.getpid(),
                 "tid": threading.get_ident(),
-                "args": {k: _jsonable(v) for k, v in args.items()},
+                "args": {**{k: _jsonable(v) for k, v in args.items()},
+                         "compiles": _compile_count() - c0},
             })
 
     def instant(self, name: str, **args: Any) -> None:

@@ -19,7 +19,9 @@ Covers the acceptance criteria of the telemetry issue:
 """
 
 import asyncio
+import contextlib
 import json
+import re
 import threading
 
 import numpy as np
@@ -29,7 +31,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.models.izhikevich_net import (IzhikevichNetConfig,
-                                              compile_model as compile_izh)
+                                              compile_model as compile_izh,
+                                              spec as izh_spec)
 from repro.core.models.mushroom_body import (MushroomBodyConfig,
                                              compile_model as compile_mb)
 from repro.core.snn.spec import SpecError
@@ -65,7 +68,8 @@ def test_span_nesting_and_chrome_export(tmp_path):
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
     assert outer["ts"] <= tick["ts"] <= outer["ts"] + outer["dur"]
     assert inner["tid"] == outer["tid"]
-    assert outer["args"] == {"model": "m"}
+    # every span counts the backend compiles it made
+    assert outer["args"] == {"model": "m", "compiles": 0}
 
     path = tmp_path / "trace.json"
     assert c.export(str(path)) == 3
@@ -354,9 +358,12 @@ def test_trace_contains_build_autotune_and_serve_spans(mb_silent_pair,
     host.run(3)
     eng.run(3)
     eng.run(3)                               # cache hit -> compile=False
+    # the mushroom body's groups are all dense; an Izhikevich net's sparse
+    # groups ask the autotuner for their propagation mode
+    compile_izh(IzhikevichNetConfig(n_total=60, n_conn=10, seed=2))
     names = {e["name"] for e in obs_trace.events()}
     assert {"build", "validate", "codegen", "shard", "run"} <= names
-    assert "choose_block_spmv" in names      # autotune decision audit
+    assert "choose_propagation" in names     # autotune decision audit
     run_spans = [e for e in obs_trace.events() if e["name"] == "run"]
     assert any(e["args"].get("sharded") for e in run_spans)
     assert any(e["args"].get("compile") is False for e in run_spans)
@@ -366,9 +373,165 @@ def test_trace_contains_build_autotune_and_serve_spans(mb_silent_pair,
     doc = json.loads(path.read_text())
     assert validate_chrome_trace(doc) is None
     tuned = [e for e in doc["traceEvents"]
-             if e["name"] == "choose_block_spmv"]
-    assert tuned and all({"bp", "bn", "occupancy"} <= set(e["args"])
-                         for e in tuned)
+             if e["name"] == "choose_propagation"]
+    assert tuned and all({"tag", "mode", "capacity", "event_occupancy"}
+                         <= set(e["args"]) for e in tuned)
+
+
+# ---------------------------------------------------------------------------
+# named scopes in the run program; the program's spans on the profiler
+# ---------------------------------------------------------------------------
+
+# 2,000 neurons x 100 targets: every group propagates in event mode
+EVENT_CFG = IzhikevichNetConfig(n_total=2000, n_conn=100, seed=3)
+
+
+def _build_event_net(sharded: bool):
+    """The event-mode net with a spike probe, as the chip benchmark runs
+    it."""
+    spec = izh_spec(EVENT_CFG)
+    spec.probe("exc_spikes", "exc", "spikes")
+    mesh = make_snn_mesh(_n_dev()) if sharded else None
+    return spec.build(dt=EVENT_CFG.dt, seed=EVENT_CFG.seed, mesh=mesh)
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["host", "sharded"])
+def event_net(request):
+    """(model, optimized HLO text of its 5-step run program)."""
+    model = _build_event_net(request.param)
+    return model, model.run_hlo(5)
+
+
+def _scoped(text: str, *parts: str) -> bool:
+    """Some instruction's op_name holds the scope segments ``parts`` in
+    order (JAX's own segments may lie between them)."""
+    pat = "(^|/)" + "/(.+/)?".join(re.escape(p) for p in parts) + "/"
+    return any(re.search(pat, n)
+               for n in re.findall(r'op_name="([^"]*)"', text))
+
+
+def _instructions(text: str) -> list:
+    """The HLO's computations and instructions, metadata stripped (the
+    source-location tables go too: they are debug information)."""
+    return [re.sub(r", metadata=\{[^}]*\}", "", ln)
+            for ln in text.splitlines()
+            if " = " in ln or ln.startswith(("HloModule", "ENTRY", "%"))]
+
+
+def test_run_hlo_names_every_group_population_and_branch(event_net):
+    model, text = event_net
+    assert {g.propagation_mode for g in model.network.synapses} == {"event"}
+    for g in model.network.synapses:
+        for part in ("compact", "event", "fallback"):
+            assert _scoped(text, f"prop/{g.name}", part), (g.name, part)
+    for pop in model.network.populations:
+        assert _scoped(text, f"neuron/{pop}")
+    for scope in ("rng", "probe"):
+        assert _scoped(text, scope)
+    assert _scoped(text, "exchange") == (model.engine is not None)
+
+
+def test_named_scopes_leave_the_program_unchanged(event_net, monkeypatch):
+    model, text = event_net
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _build_event_net(model.engine is not None).run_hlo(5)
+    assert "prop/" not in plain
+    assert _instructions(plain) == _instructions(text)
+
+
+def test_run_is_a_span_on_the_jax_profiler(tmp_path):
+    from jax.profiler import ProfileData
+    model = compile_izh(IzhikevichNetConfig(n_total=60, n_conn=10, seed=4))
+    jax.block_until_ready(model.run(2))
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(model.run(2))
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events}
+    assert "repro.run" in names
+
+
+def test_run_span_counts_its_compiles():
+    model = compile_izh(IzhikevichNetConfig(n_total=60, n_conn=10, seed=5))
+    seen = len(obs_trace.events())
+    # a persistent-cache hit would load instead of compile
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for n in (3, 3, 4):
+            jax.block_until_ready(model.run(n))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    runs = [e["args"] for e in obs_trace.events()[seen:]
+            if e["name"] == "run"]
+    assert [a["n_steps"] for a in runs] == [3, 3, 4]
+    first, repeat, new_length = (a["compiles"] for a in runs)
+    assert first >= 1 and repeat == 0 and new_length >= 1
+
+
+def test_run_hlo_is_the_program_run_executed():
+    model = compile_izh(IzhikevichNetConfig(n_total=60, n_conn=10, seed=6))
+    jax.block_until_ready(model.run(3, gscales={"exc": 1.5}))
+    programs = len(model._run_cache)
+    assert "prop/" in model.run_hlo(3, gscales={"exc": 1.5})
+    assert len(model._run_cache) == programs       # nothing built anew
+    model.run_hlo(3)                               # another program
+    assert len(model._run_cache) == programs + 1
+
+
+@contextlib.contextmanager
+def _persistent_cache(path):
+    """JAX's persistent compile cache in ``path`` for the block, every
+    program cached however quick its compile; the process's cache settings
+    come back after it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_compilation_cache_include_metadata_in_key")
+    was = {n: getattr(jax.config, n) for n in names}
+    cc.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", str(path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        yield
+    finally:
+        for n, v in was.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
+
+
+def test_run_hlo_names_the_source_that_ran_with_the_cache_on(tmp_path,
+                                                             monkeypatch):
+    """Under the entry points' persistent cache a program that differs from
+    a cached one only in its scope names compiles anew, so run_hlo names
+    the scopes of the source that ran (a key without metadata would load
+    the cached program, names and all)."""
+    from repro.launch import compile_cache as CC
+    cfg = IzhikevichNetConfig(n_total=60, n_conn=10, seed=7)
+    monkeypatch.setenv(CC.CACHE_ENV, str(tmp_path))
+    with _persistent_cache(tmp_path):
+        CC.enable_compile_cache()
+        with monkeypatch.context() as m:
+            scope = jax.named_scope
+            m.setattr(jax, "named_scope",
+                      lambda name: scope(name.replace("prop/", "old/")))
+            jax.block_until_ready(compile_izh(cfg).run(3))
+        assert any(tmp_path.iterdir())               # the old names cached
+        model = compile_izh(cfg)
+        jax.block_until_ready(model.run(3))
+        text = model.run_hlo(3)
+    assert "prop/" in text and "old/" not in text
+
+
+def test_run_hlo_refuses_a_cache_keyed_without_metadata(tmp_path):
+    model = compile_izh(IzhikevichNetConfig(n_total=60, n_conn=10, seed=8))
+    with _persistent_cache(tmp_path):
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        with pytest.raises(RuntimeError, match="metadata"):
+            model.run_hlo(3)
 
 
 # ---------------------------------------------------------------------------

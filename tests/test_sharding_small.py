@@ -101,6 +101,7 @@ def test_compile_cache_follows_env_else_fixed_checkout_dir(monkeypatch,
     from repro.launch import compile_cache as CC
 
     was = jax.config.jax_compilation_cache_dir
+    was_meta = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv(CC.CACHE_ENV, str(tmp_path))
         jax.config.update("jax_compilation_cache_dir", None)
@@ -114,3 +115,5 @@ def test_compile_cache_follows_env_else_fixed_checkout_dir(monkeypatch,
         assert Path(path).parent == Path(__file__).resolve().parents[1]
     finally:
         jax.config.update("jax_compilation_cache_dir", was)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          was_meta)
