@@ -6,9 +6,11 @@ Everything else is found by name:
     chipbench/configs/<configuration>.json   the network as it is run
     chipbench/traffic/<traffic>.json         the calls, with their mode
     chipbench/modes/<mode>.py                how a mode drives and checks
-    chipbench/reference/<reference>.py       the network's plain reference
+    chipbench/reference/<reference>.py       the network's plain reference,
+                                             and its layout's work counts
     chipbench/limits/<workload>.json         the limits of the check
     chipbench/metrics/<metric>.py            one metric's reduction
+    chipbench/tests/small/<workload>.json    the cell's size in CPU tests
 
 The system under test is the ``repro`` package in ``src/`` of the
 checkout; the benchmark takes from it only the model, its outputs and the
@@ -20,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 import json
+import math
 import shutil
 import sys
 import tempfile
@@ -29,6 +32,7 @@ from pathlib import Path
 import jax
 
 from chipbench import check
+from chipbench import scopes as sc
 from chipbench import trace as tr
 from chipbench import work
 
@@ -94,19 +98,40 @@ def device_info(chips: int, require_chip: bool = True) -> dict:
 
 
 class CompileClock:
-    """Seconds and count of JAX's compile events (tracing, lowering,
-    compiling) while it is installed."""
+    """Wall seconds in which JAX compiled (traced, lowered or compiled a
+    program), and the count of backend compiles, while it is installed.
+
+    JAX reports each ``/jax/core/compile/*`` event as a span, and spans
+    nest: tracing a jitted function traces the jitted functions it calls,
+    each with its own event inside the outer one.  So the seconds are the
+    length of the spans' union, which counts a nested span once and can
+    never exceed the wall time it is read over."""
+
+    PREFIX = "/jax/core/compile/"
+    BACKEND = PREFIX + "backend_compile_duration"
 
     def __init__(self):
-        self.seconds = 0.0
+        self.spans = []
         self.events = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        jax.monitoring.register_event_time_span_listener(self.record)
 
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event.startswith("/jax/core/compile/"):
-            self.seconds += duration
-            if event == "/jax/core/compile/backend_compile_duration":
+    def record(self, event: str, start: float, end: float, **_) -> None:
+        """One span of ``time.time()`` seconds."""
+        if event.startswith(self.PREFIX):
+            self.spans.append((start, end))
+            if event == self.BACKEND:
                 self.events += 1
+
+    def seconds(self, since: float = -math.inf,
+                until: float = math.inf) -> float:
+        """Length of the union of the spans, clipped to [since, until)."""
+        total, covered = 0.0, since
+        for s, e in sorted(self.spans):
+            s, e = max(s, covered), min(e, until)
+            if e > s:
+                total += e - s
+            covered = max(covered, e)
+        return total
 
 
 def configure_jax() -> str:
@@ -164,10 +189,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"device {device}; kernel backend {ops.backend()}; "
         f"compile cache {cache}")
 
-    c0, t0 = clock.seconds, time.perf_counter()
+    t0 = time.time()
     model = build_model(cell.config, mseed)
-    build_wall = time.perf_counter() - t0
-    build_compile = clock.seconds - c0
+    t1 = time.time()
+    build_wall = t1 - t0
+    build_compile = clock.seconds(t0, t1)
     for p in propagation_plan(model):
         log(f"propagation {p}")
     mode_mod = importlib.import_module(
@@ -175,7 +201,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     mode = mode_mod.Mode(model, cell.config, cell.traffic, mseed)
     mode.warm_up()
     setup_s = time.perf_counter() - t_start
-    setup_compile = clock.seconds
+    setup_compile = clock.seconds()
     log(f"set-up {setup_s:.3f} s: build {build_wall:.3f} s "
         f"({build_compile:.3f} s compiling), compiling in all "
         f"{setup_compile:.3f} s")
@@ -201,6 +227,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     stats = jax.devices()[0].memory_stats() or {}
     device["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
     spikes = mode.spike_totals()
+    # the text of the programs the window ran, to map its ops to scopes:
+    # cached by now, so nothing compiles
+    hlo = mode.program_hlo() if trace else None
     mode.release()
     del model
     ref = importlib.import_module(
@@ -211,9 +240,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                   * float(cell.config["model_config"]["dt"]) * 1e-3)
         log(f"window rate {pop} {hz:.3f} Hz ({n} spikes)")
 
-    reduced = None
+    reduced = by_scope = None
     if trace:
         reduced = tr.reduce_dir(logdir, SPAN)
+        by_scope = sc.reduce_dir(logdir, hlo, SPAN)
         shutil.rmtree(logdir, ignore_errors=True)
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
@@ -231,7 +261,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         "window": {"seconds": elapsed, "net_steps": steps, "calls": calls},
         "setup": {"setup_s": setup_s, "compile_s": setup_compile,
                   "build_s": build_wall - build_compile},
-        "trace": reduced, "peak": peak,
+        "trace": reduced, "scopes": by_scope, "peak": peak,
         "work": work.necessary(layout, spikes, steps),
     }
     wanted = cell.per_layer if trace else cell.end_to_end
@@ -250,7 +280,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     result = {"correct": bool(correct), "attempted": checked,
               "failed": failed, "metrics": metrics, "device": device}
     if reduced is not None:
-        result["breakdown"] = {"device_ops": reduced["device_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
+        result["breakdown"] = {
+            "device_ops": reduced["device_ops"],
+            "idle_gaps": reduced["idle_gaps"],
+            "scopes": by_scope["scopes"],
+            "idle_gaps_program": by_scope["idle_gaps_program"]}
     result["checks"] = checks
     return result

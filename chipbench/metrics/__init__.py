@@ -4,5 +4,7 @@ metric's value, or None where the run holds nothing to read it from.
 
 ``ctx`` holds ``window`` (seconds, net_steps, calls of the measured
 window), ``setup`` (setup_s, compile_s, build_s), ``trace`` (the reduced
-profiler trace of a traced run, else None), ``peak`` (the device's peaks)
-and ``work`` (the window's necessary bytes and operations)."""
+profiler trace of a traced run, else None: ``chipbench.trace``),
+``scopes`` (the same trace reduced by the program's named scopes, else
+None: ``chipbench.scopes``), ``peak`` (the device's peaks) and ``work``
+(the window's necessary bytes and operations: ``chipbench.work``)."""

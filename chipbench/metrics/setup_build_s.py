@@ -1,5 +1,6 @@
 """Host seconds of ModelSpec.build until the connectivity is on the
-device, less the compile seconds inside it."""
+device, less the seconds in which JAX compiled inside it (the union of
+its compile spans, so never more than the build's wall time)."""
 
 
 def read(ctx):

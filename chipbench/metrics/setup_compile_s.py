@@ -1,5 +1,6 @@
-"""Seconds of JAX's /jax/core/compile/* events (tracing, lowering,
-compiling) during set-up."""
+"""Wall seconds during set-up in which JAX compiled: the union of its
+/jax/core/compile/* spans (tracing, lowering, compiling), each nested span
+counted once."""
 
 
 def read(ctx):

@@ -6,7 +6,8 @@ default) are the chain's first links.
 The traffic's ``initial`` draws neuron variables of the initial state
 from the seed, the same for the program and the reference:
 ``{"uniform": {"V": [lo, hi], ...}}`` sets each population's ``V`` to
-independent uniforms on [lo, hi).  A network started from its uniform
+independent uniforms on [lo, hi), ``{"normal": {"V": [mean, sd]}}`` to
+independent normals.  A network started from its uniform
 rest state fires in one synchronous burst and then falls silent for
 some hundred steps; one started desynchronised runs in its steady
 regime from the start."""
@@ -24,23 +25,39 @@ from chipbench.harness import SPAN
 NUMBERS = ("flip_margin", "spike_flips_ppm", "count_gap", "v_gap_mV",
            "finite_mismatch")
 INITIAL_SALT = 0x1417
+NORMAL_SALT = 0x4E
 
 
 def initial_neurons(neurons: dict, rule: dict, seed: int) -> dict:
     """``neurons`` (pop -> {var: array}) with the variables that ``rule``
     names drawn from the seed; the draw depends on the seed, the
-    population's name and order, the variable and the size alone."""
+    population's name and order, the variable, the distribution and the
+    size alone."""
+    both = set(rule.get("uniform", {})) & set(rule.get("normal", {}))
+    if both:
+        raise ValueError(f"variables drawn twice: {sorted(both)}")
     key = jax.random.fold_in(jax.random.PRNGKey(seed), INITIAL_SALT)
     out = {}
     for i, pop in enumerate(sorted(neurons)):
         out[pop] = dict(neurons[pop])
-        for j, (var, (lo, hi)) in enumerate(
-                sorted(rule.get("uniform", {}).items())):
-            x = neurons[pop][var]
-            k = jax.random.fold_in(jax.random.fold_in(key, i), j)
-            out[pop][var] = jax.random.uniform(
-                k, x.shape, jnp.float32, float(lo), float(hi)).astype(x.dtype)
+        for kind, draw in (("uniform", _uniform), ("normal", _normal)):
+            for j, (var, args) in enumerate(sorted(rule.get(kind,
+                                                            {}).items())):
+                x = neurons[pop][var]
+                k = jax.random.fold_in(jax.random.fold_in(key, i), j)
+                if kind == "normal":       # uniform keys predate normal
+                    k = jax.random.fold_in(k, NORMAL_SALT)
+                out[pop][var] = draw(k, x.shape, *map(float, args)).astype(
+                    x.dtype)
     return out
+
+
+def _uniform(key, shape, lo: float, hi: float):
+    return jax.random.uniform(key, shape, jnp.float32, lo, hi)
+
+
+def _normal(key, shape, mean: float, sd: float):
+    return mean + sd * jax.random.normal(key, shape, jnp.float32)
 
 
 class Mode:
@@ -88,6 +105,11 @@ class Mode:
         timed = self.links[len(self.links) - self.timed:]
         return {p: sum(int(link["counts"][p].sum()) for link in timed)
                 for p in self.pops}
+
+    def program_hlo(self) -> str:
+        """The optimized HLO text of the program the calls ran (the
+        compiled one: nothing is compiled again)."""
+        return self.model.run_hlo(self.steps, state=self.state)
 
     def release(self) -> None:
         self.model = None

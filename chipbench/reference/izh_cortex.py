@@ -38,14 +38,20 @@ def sizes(cfg: dict) -> tuple[int, int, int]:
     return n, n_exc, n - n_exc
 
 
+# operations per neuron-step of the update below, counted by hand: 9 per
+# half-step on V (x2), 5 for U, clamp, threshold, reset (2 selects and an
+# add) = 28; state V and U
+IZHIKEVICH = {"state_vars": 2, "flops": 28}
+
+
 def layout(cfg: dict) -> dict:
-    """Populations and synapse populations with their sizes, for the
-    necessary-work counters."""
+    """Populations and synapse populations with their sizes and counts,
+    for the necessary-work counters (``chipbench.work``)."""
     n, n_exc, n_inh = sizes(cfg)
     k = int(cfg["n_conn"])
     return {
-        "populations": {"exc": {"n": n_exc, "model": "izhikevich"},
-                        "inh": {"n": n_inh, "model": "izhikevich"}},
+        "populations": {"exc": {"n": n_exc, **IZHIKEVICH},
+                        "inh": {"n": n_inh, **IZHIKEVICH}},
         # every pre neuron reaches k targets; Pulse synapses keep no state
         "synapses": [{"pre": "exc", "fanout": k, "psm_vars": 0, "n_post": n},
                      {"pre": "inh", "fanout": k, "psm_vars": 0, "n_post": n}],

@@ -19,28 +19,21 @@ executable that ran (``CompiledModel.run_hlo``):
   ``prop/<group>``), the innermost layer names it.  A fusion carries its
   root instruction's op_name, so a fusion whose instructions span two
   scopes is charged to its root's;
-* each op's module is the event on the same device's ``XLA Modules`` line
-  that encloses it (``jit__run(1538...)`` -> ``jit__run``);
-* each op's self time (``chipbench.trace.self_times``) inside the
-  benchmark's window goes to its scope, or to no scope: an instruction
-  without a vocabulary segment (the scan's bookkeeping, copies XLA adds)
-  or one of a module whose HLO text was not given;
-* idle gaps are the window's stretches with no op on the device, each
-  labelled by the program span (``repro.<name>``, the profiler annotation
-  of ``repro.obs.trace.span``) that overlaps it most, the innermost on a
-  tie, else ``none``.
-
-Seconds are averaged over the devices, as ``chipbench.trace`` does.
+* each op's self time inside the benchmark's window goes to its scope
+  (``chipbench.trace.reduce_profile`` with the scope as its key: the op's
+  module and instruction name), or to no scope: an instruction without a
+  vocabulary segment (the scan's bookkeeping, copies XLA adds) or one of
+  a module whose HLO text was not given;
+* idle gaps are labelled by the program's spans (``idle_gaps_program``
+  of that reduction).
 """
 
 from __future__ import annotations
 
 import re
-from collections import defaultdict
 
 from chipbench import trace as tr
 
-PROGRAM_PREFIX = "repro."  # the program's profiler spans
 LAYERS = ("prop", "neuron", "custom", "rng", "probe", "health", "exchange")
 NAMED = ("prop", "neuron", "custom")      # followed by a name segment
 PROP_PARTS = ("compact", "event", "fallback", "dense")
@@ -49,7 +42,6 @@ UNSCOPED = "none"
 _MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.MULTILINE)
 _INSTR = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?\bmetadata=\{[^}]*?'
                     r'\bop_name="([^"]*)"', re.MULTILINE)
-_FINGERPRINT = re.compile(r"\(\d+\)$")
 
 
 def scope_of(op_name: str):
@@ -83,91 +75,24 @@ def hlo_scopes(text: str) -> dict:
     return out
 
 
-def _module_name(event_name: str) -> str:
-    """``jit__run(15388027131515875373)`` -> ``jit__run``."""
-    return _FINGERPRINT.sub("", event_name)
-
-
-def _enclosing(modules, s: float) -> str:
-    for a, b, name in modules:
-        if a <= s < b:
-            return name
-    return ""
-
-
 def reduce_profile(pd, scopes: dict, span_prefix: str) -> dict:
     """Device seconds by scope, and idle gaps by program span, of one
     ProfileData inside the benchmark's window span (see the module doc).
     ``scopes`` is ``hlo_scopes(...)`` of the executables that ran."""
-    host = []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                host.append((ev.name, ev.start_ns,
-                             ev.start_ns + ev.duration_ns))
-    windows = [(s, e) for n, s, e in host if n == span_prefix + tr.WINDOW]
-    if not windows:
-        raise ValueError(f"no {span_prefix}{tr.WINDOW} span in the trace")
-    w0, w1 = windows[0]
-    spans = [(n[len(PROGRAM_PREFIX):], s, e) for n, s, e in host
-             if n.startswith(PROGRAM_PREFIX)]
+    def scope(module: str, name: str) -> str:
+        return scopes.get((module, tr.instruction(name))) or UNSCOPED
 
-    devices = [p for p in pd.planes
-               if p.name.startswith("/device:") and tr._op_lines(p)]
-    if not devices:
-        raise ValueError("no device op line in the trace")
-    scope_s = defaultdict(float)
-    busy_ns = 0.0
-    gaps = []
-    for plane in devices:
-        modules = sorted((ev.start_ns, ev.start_ns + ev.duration_ns,
-                          _module_name(ev.name))
-                         for line in plane.lines
-                         if line.name == "XLA Modules" for ev in line.events)
-        ivs = []
-        for line in tr._op_lines(plane):
-            evs = []
-            for ev in line.events:
-                s = max(ev.start_ns, w0)
-                e = min(ev.start_ns + ev.duration_ns, w1)
-                if e > s:
-                    evs.append((s, e, ev.name))
-            for s, e, own, name in tr.self_times(evs):
-                ivs.append((s, e))
-                instr = name.split(" = ", 1)[0].lstrip("%")
-                key = (_enclosing(modules, s), instr)
-                scope_s[scopes.get(key) or UNSCOPED] += own
-        merged = tr._merge(ivs)
-        busy_ns += sum(e - s for s, e in merged)
-        edges = [w0] + [x for iv in merged for x in iv] + [w1]
-        gaps += [(edges[i], edges[i + 1])
-                 for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
-    n = len(devices)
-    labelled = sorted(((_label(s, e, spans), (e - s) / 1e9)
-                       for s, e in gaps), key=lambda g: -g[1])
+    r = tr.reduce_profile(pd, span_prefix, key=scope)
+    scope_s = dict(r["ops_s"])
     unscoped = scope_s.pop(UNSCOPED, 0.0)
     return {
-        "window_s": (w1 - w0) / 1e9,
-        "busy_s": busy_ns / 1e9 / n,
-        "unscoped_s": unscoped / 1e9 / n,
-        "scope_s": {k: v / 1e9 / n for k, v in scope_s.items()},
-        "scopes": [[k, v / 1e9 / n] for k, v in
-                   sorted(scope_s.items(), key=lambda kv: -kv[1])[:tr.TOP]],
-        "idle_gaps_program": [[k, v / n] for k, v in labelled[:tr.TOP]],
+        "window_s": r["window_s"],
+        "busy_s": r["busy_s"],
+        "unscoped_s": unscoped,
+        "scope_s": scope_s,
+        "scopes": tr.top(scope_s.items()),
+        "idle_gaps_program": r["idle_gaps_program"],
     }
-
-
-def _label(s: float, e: float, spans) -> str:
-    """The span overlapping [s, e) most; of equal overlaps the shortest
-    (the innermost)."""
-    best, most = UNSCOPED, (0.0, 0.0)
-    for name, a, b in spans:
-        ov = (min(b, e) - max(a, s), a - b)
-        if ov[0] > 0 and ov > most:
-            best, most = name, ov
-    return best
 
 
 def shares(r: dict):
@@ -193,6 +118,14 @@ def shares(r: dict):
         "neuron_update_share": 100.0 * neuron / busy,
         "unscoped_share": 100.0 * r["unscoped_s"] / busy,
     }
+
+
+def read(ctx: dict, name: str):
+    """One share of a run's reduction by scope (``ctx["scopes"]``), or
+    None where the run was not traced or ran no op under a scope."""
+    r = ctx["scopes"]
+    s = shares(r) if r is not None else None
+    return s[name] if s is not None else None
 
 
 def reduce_dir(logdir: str, hlo_text: str, span_prefix: str) -> dict:

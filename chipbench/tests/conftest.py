@@ -5,6 +5,7 @@ checkout; JAX runs on the CPU here."""
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 from pathlib import Path
@@ -16,18 +17,19 @@ import pytest  # noqa: E402
 
 from chipbench import harness  # noqa: E402
 
-# sizes at which the models run in seconds on a CPU
-SMALL = {
-    "izh100k.run": {"n_total": 2000, "n_conn": 100},
-}
+# a cell's configuration cut to a size at which it runs in seconds on a
+# CPU: small/<workload>.json holds the model_config keys it changes
+SMALL = Path(__file__).resolve().parent / "small"
+WORKLOADS = sorted(p.name[:-len(".json")] for p in SMALL.glob("*.json"))
 
 
 def small_cell(workload: str) -> harness.Cell:
     cell = harness.load_cell(workload)
-    cell.config["model_config"].update(SMALL[workload])
+    cell.config["model_config"].update(
+        json.loads((SMALL / f"{workload}.json").read_text()))
     return cell
 
 
-@pytest.fixture(params=sorted(SMALL))
+@pytest.fixture(params=WORKLOADS)
 def workload(request):
     return request.param

@@ -8,7 +8,7 @@ import time
 import pytest
 
 from chipbench import harness
-from chipbench.tests.conftest import small_cell
+from chipbench.tests.conftest import WORKLOADS, small_cell
 from repro.core.snn import simulator as S
 from repro.core.snn import spec as SP
 
@@ -57,15 +57,17 @@ def spike_altered(monkeypatch):
     monkeypatch.setattr(SP.CompiledModel, "run", flipped)
 
 
-# izh100k.run has no batch and no exchange between chips: a step that
-# returns its state unchanged, and an answer altered where it is produced
+# by the traffic's mode: a run-mode cell has no batch and no exchange
+# between chips, so a step that returns its state unchanged, and an answer
+# altered where it is produced
 FAULTS = {
-    "izh100k.run": [state_unchanged, count_altered, spike_altered],
+    "run": [state_unchanged, count_altered, spike_altered],
 }
 
 
 @pytest.mark.parametrize("workload,fault", [
-    (w, f) for w, fs in FAULTS.items() for f in fs],
+    (w, f) for w in WORKLOADS
+    for f in FAULTS[harness.load_cell(w).traffic["mode"]]],
     ids=lambda x: getattr(x, "__name__", x))
 def test_fault_makes_run_incorrect(workload, fault, monkeypatch):
     fault(monkeypatch)

@@ -1,8 +1,9 @@
 """Device time by named scope: by hand on a synthetic trace and HLO map,
 and on a trace recorded on a TPU v5e with the HLO text of the program it
-ran (``record_scopes.py``: two 10-step calls of izh100k.run)."""
+ran (``record_trace.py``: two 10-step calls of izh100k.run)."""
 
 import gzip
+import importlib
 import json
 from pathlib import Path
 from types import SimpleNamespace as NS
@@ -208,3 +209,20 @@ def test_recorded_trace_existing_reduction_unchanged():
     expected = json.loads((DATA / f"{RECORDED}.json").read_text())
     for k in ("window_s", "busy_s", "prop_s"):
         assert r[k] == pytest.approx(expected[k], rel=1e-9)
+
+
+SCOPE_METRICS = ("prop_scope_share", "prop_fallback_share",
+                 "neuron_update_share", "unscoped_share")
+
+
+@pytest.mark.parametrize("name", SCOPE_METRICS)
+def test_scope_metric_reads_the_recorded_trace(name):
+    pd, scopes = _recorded()
+    reader = importlib.import_module(f"chipbench.metrics.{name}")
+    expected = sc.shares(
+        json.loads((DATA / f"{RECORDED}.scopes.json").read_text()))
+    ctx = {"scopes": sc.reduce_profile(pd, scopes, P)}
+    assert reader.read(ctx) == pytest.approx(expected[name], rel=1e-9)
+    assert reader.read({"scopes": None}) is None
+    # a program without named scopes reads nothing
+    assert reader.read({"scopes": sc.reduce_profile(pd, {}, P)}) is None

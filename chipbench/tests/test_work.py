@@ -19,6 +19,27 @@ def test_izhikevich_counts_by_hand():
     assert w["flops"] == 4 * 10 * 28 + 21
 
 
+def test_counts_come_from_the_layout():
+    # a model of 3 state variables and 41 operations, delayed events of
+    # 20 bytes into a synapse population with one conductance
+    layout = {"populations": {"a": {"n": 6, "state_vars": 3, "flops": 41}},
+              "synapses": [{"pre": "a", "fanout": 2, "psm_vars": 1,
+                            "n_post": 6, "event_bytes": 20}]}
+    w = work.necessary(layout, {"a": 5}, net_steps=3)
+    assert w == {"events": 10, "prop_bytes": 200,
+                 "state_bytes": 3 * (6 * 3 + 6) * 2 * 4,
+                 "flops": 3 * (6 * 41 + 5 * 6) + 10}
+
+
+@pytest.mark.parametrize("key", ["state_vars", "flops"])
+def test_a_layout_without_a_count_raises(key):
+    layout = izh_cortex.layout({"n_total": 10, "exc_frac": 0.8,
+                                "n_conn": 3})
+    del layout["populations"]["inh"][key]
+    with pytest.raises(ValueError, match=f"'inh'.*'{key}'"):
+        work.necessary(layout, {"exc": 1, "inh": 1}, net_steps=1)
+
+
 def test_least_seconds_takes_the_binding_bound():
     peak = {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
     w = {"prop_bytes": 4e9, "state_bytes": 1e9, "flops": 1e12}

@@ -6,11 +6,18 @@ change replaces a kernel:
 
 * a synaptic event (one spike reaching one target) reads its weight and
   target index and reads and writes the target's accumulator: 16 bytes
-  and one add;
+  and one add, unless the synapse population's entry gives its own
+  ``event_bytes`` (a delayed event also reads its delay);
 * every neuron's state and every post-synaptic conductance is read and
   written once per step;
-* each neuron model costs the operations its update takes, counted by
-  hand from its equations (``MODEL_COST``; a transcendental counts as one).
+* each neuron costs the operations its update takes.
+
+The counts of a network come from its plain reference's ``layout``: each
+population gives ``n``, ``state_vars`` and ``flops`` (its model's
+operations per neuron-step, counted by hand from its equations, a
+transcendental as one), each synapse population ``pre``, ``fanout``,
+``psm_vars``, ``n_post`` and optionally ``event_bytes``.  So a new neuron
+model brings its counts with its reference, and nothing here names one.
 
 At the benchmark's network sizes the bytes bound the least time: the operations
 would take well under a hundredth of it at the chip's peak.
@@ -28,13 +35,6 @@ EVENT_FLOPS = 1
 WORD = 4                  # float32 state
 PSM_FLOPS = 5             # decay, add, reversal term, product, accumulate
 
-# neuron model -> state variables and operations per neuron-step:
-#   izhikevich: 9 per half-step on V (x2), 5 for U, clamp, threshold,
-#               reset (2 selects and an add)                      = 28
-MODEL_COST = {
-    "izhikevich": {"state_vars": 2, "flops": 28},
-}
-
 
 class UnknownDevice(KeyError):
     """The device kind has no entry in peaks.json."""
@@ -51,18 +51,27 @@ def peaks(device_kind: str) -> dict:
 
 def necessary(layout: dict, spikes: dict, net_steps: int) -> dict:
     """Bytes and operations that ``net_steps`` network-steps need, given
-    the spikes each population fired in them (summed over networks)."""
-    events = sum(int(spikes[s["pre"]]) * s["fanout"]
-                 for s in layout["synapses"])
-    state_words = sum(p["n"] * MODEL_COST[p["model"]]["state_vars"]
-                      for p in layout["populations"].values())
+    the spikes each population fired in them (summed over networks).
+    Raises ValueError naming a population whose count the layout leaves
+    out."""
+    state_words = step_flops = 0
+    for name, p in layout["populations"].items():
+        for key in ("state_vars", "flops"):
+            if key not in p:
+                raise ValueError(
+                    f"the layout's population {name!r} gives no {key!r}")
+        state_words += p["n"] * p["state_vars"]
+        step_flops += p["n"] * p["flops"]
+    events = prop_bytes = 0
+    for s in layout["synapses"]:
+        n = int(spikes[s["pre"]]) * s["fanout"]
+        events += n
+        prop_bytes += n * s.get("event_bytes", EVENT_BYTES)
     psm_words = sum(s["psm_vars"] * s["n_post"] for s in layout["synapses"])
-    step_flops = (sum(p["n"] * MODEL_COST[p["model"]]["flops"]
-                      for p in layout["populations"].values())
-                  + PSM_FLOPS * psm_words)
+    step_flops += PSM_FLOPS * psm_words
     return {
         "events": events,
-        "prop_bytes": events * EVENT_BYTES,
+        "prop_bytes": prop_bytes,
         "state_bytes": net_steps * (state_words + psm_words) * 2 * WORD,
         "flops": net_steps * step_flops + events * EVENT_FLOPS,
     }
